@@ -48,11 +48,12 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import approx_gemm as ag
 from repro.launch import hlo_analysis
+from repro.launch.mesh import make_mesh
 
 SMOKE = {smoke}
 FAST = {fast}
 REPS = {reps}
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 TP = 8
 
 GEMM_SHAPES = ([(16, 64, 32)] if SMOKE
@@ -177,7 +178,9 @@ def run(fast: bool = True, smoke: bool = False, reps: int = 3):
     sys.path.insert(0, _REPO + "/src")
     from repro.launch.hostdev import force_host_devices
 
-    env = force_host_devices(N_DEVICES, dict(os.environ))
+    # the child emulates the mesh on host devices and must never compete
+    # with this process (or any other) for an accelerator
+    env = force_host_devices(N_DEVICES, dict(os.environ, JAX_PLATFORMS="cpu"))
     code = ("import sys; sys.path.insert(0, %r)\n" % (_REPO + "/src")
             + _CHILD.format(smoke=smoke, fast=fast,
                             reps=1 if smoke else reps))

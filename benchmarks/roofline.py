@@ -1,15 +1,17 @@
 """Roofline analysis from the dry-run artifacts (deliverable g).
 
-Reads experiments/dryrun/*.json and derives, per (arch x shape x mesh):
+Reads experiments/dryrun/*.json and derives, per (arch x shape x mesh),
+with the peaks of the cell's `device_kind` (`peaks`):
 
-  compute term    = hlo_flops / (chips * 197 TFLOP/s bf16)
-  memory term     = hlo_bytes / (chips * 819 GB/s HBM)
-  collective term = collective_bytes / (chips * 50 GB/s ICI per link)
+  compute term    = hlo_flops / peak bf16 FLOP/s
+  memory term     = hlo_bytes / peak HBM bytes/s
+  collective term = collective_bytes / ICI bytes/s per link
 
 hlo_* are per-device already (post-SPMD HLO), so the per-chip division
 is folded in; the dominant term is the bottleneck, and
 MODEL_FLOPS / HLO_FLOPS measures how much compiled compute is useful
-(remat + masked-attention + dispatch overcompute show up here)."""
+(remat + masked-attention + dispatch overcompute show up here).  A
+device kind without published peaks is an error, never a default."""
 
 from __future__ import annotations
 
@@ -18,9 +20,23 @@ import json
 import os
 import time
 
-PEAK_FLOPS = 197e12        # bf16 per chip (TPU v5e class)
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+# Published per-chip peaks keyed by jax's `device_kind` (Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s chip-to-chip interconnect over 4 links).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm": 819e9, "ici": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak bf16 FLOP/s, HBM and per-link ICI bytes/s of one chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device_kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") \
+            from None
+
 
 _BASE = os.path.join(os.path.dirname(__file__), "..", "experiments")
 # prefer the post-hillclimb matrix when it exists (see EXPERIMENTS.md §Perf)
@@ -39,9 +55,10 @@ def load_cells(pattern: str = "*.json", d: str = DRYRUN_DIR):
             continue
         n = r["n_devices"]
         hlo = r["hlo"]
-        r["t_compute"] = hlo["flops"] / PEAK_FLOPS
-        r["t_memory"] = hlo["bytes"] / HBM_BW
-        r["t_collective"] = hlo["collective_bytes"] / ICI_BW
+        pk = peaks(r.get("device_kind"))
+        r["t_compute"] = hlo["flops"] / pk["flops"]
+        r["t_memory"] = hlo["bytes"] / pk["hbm"]
+        r["t_collective"] = hlo["collective_bytes"] / pk["ici"]
         terms = {"compute": r["t_compute"], "memory": r["t_memory"],
                  "collective": r["t_collective"]}
         r["bottleneck"] = max(terms, key=terms.get)
@@ -49,7 +66,7 @@ def load_cells(pattern: str = "*.json", d: str = DRYRUN_DIR):
         # useful-compute ratio: model flops per device vs compiled flops
         r["useful_ratio"] = (r["model_flops"] / n) / max(hlo["flops"], 1.0)
         # roofline fraction: ideal compute time / bound time
-        r["roofline_frac"] = (r["model_flops"] / n / PEAK_FLOPS) / \
+        r["roofline_frac"] = (r["model_flops"] / n / pk["flops"]) / \
             max(r["t_bound"], 1e-12)
         cells.append(r)
     return cells

@@ -3,8 +3,9 @@ the per-kernel harnesses (bench_kernels -> BENCH_kernels.json +
 BENCH_dispatch.json; bench_conv -> BENCH_conv.json; bench_attn ->
 BENCH_attn.json; bench_serve -> BENCH_serve.json; bench_faults ->
 BENCH_faults.json; bench_obs -> BENCH_obs.json; bench_dse ->
-BENCH_dse.json).  Prints
-``name,us_per_call,derived`` CSV at the end.
+BENCH_dse.json; bench_shard -> BENCH_shard.json).  Prints
+``name,us_per_call,derived`` CSV at the end, and exits non-zero when any
+phase failed.
 
 Flags:
   --fast      skip the slow CNN table; smaller kernel shape sweep
@@ -14,6 +15,7 @@ Flags:
 
 from __future__ import annotations
 
+import functools
 import sys
 import traceback
 
@@ -32,89 +34,45 @@ def main() -> None:
         mods = [table2_ppa, table3_psnr, table5_yield, roofline]
     if "--kernels" in sys.argv or smoke:
         mods = []
-    rows = []
-    for mod in mods:
-        try:
-            rows.extend(mod.run())
-        except Exception as e:  # noqa: BLE001
-            traceback.print_exc()
-            rows.append((mod.__name__.split(".")[-1], 0.0,
-                         f"ERROR:{type(e).__name__}"))
-    kern_path = (bench_kernels.OUT_PATH_SMOKE if smoke
-                 else bench_kernels.OUT_PATH)
-    disp_path = (bench_kernels.DISPATCH_PATH_SMOKE if smoke
-                 else bench_kernels.DISPATCH_PATH)
-    try:
-        rows.extend(bench_kernels.run(fast=fast or "--kernels" in sys.argv,
-                                      smoke=smoke))
-        print(f"kernel records -> {kern_path}")
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_kernels", 0.0, f"ERROR:{type(e).__name__}"))
-    try:
-        rows.extend(bench_kernels.run_dispatch(
-            fast=fast or "--kernels" in sys.argv, smoke=smoke))
-        print(f"dispatch records -> {disp_path}")
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_dispatch", 0.0, f"ERROR:{type(e).__name__}"))
-    conv_path = bench_conv.OUT_PATH_SMOKE if smoke else bench_conv.OUT_PATH
-    try:
-        rows.extend(bench_conv.run(fast=fast or "--kernels" in sys.argv,
-                                   smoke=smoke))
-        print(f"conv records -> {conv_path}")
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_conv", 0.0, f"ERROR:{type(e).__name__}"))
-    attn_path = bench_attn.OUT_PATH_SMOKE if smoke else bench_attn.OUT_PATH
-    try:
-        rows.extend(bench_attn.run(fast=fast or "--kernels" in sys.argv,
-                                   smoke=smoke))
-        print(f"attn records -> {attn_path}")
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_attn", 0.0, f"ERROR:{type(e).__name__}"))
-    try:
-        rows.extend(bench_serve.run(fast=fast or "--kernels" in sys.argv,
-                                    smoke=smoke))
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_serve", 0.0, f"ERROR:{type(e).__name__}"))
-    try:
-        rows.extend(bench_faults.run(fast=fast or "--kernels" in sys.argv,
-                                     smoke=smoke))
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_faults", 0.0, f"ERROR:{type(e).__name__}"))
-    try:
-        rows.extend(bench_obs.run(fast=fast or "--kernels" in sys.argv,
-                                  smoke=smoke))
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_obs", 0.0, f"ERROR:{type(e).__name__}"))
-    try:
-        rows.extend(bench_dse.run(fast=fast or "--kernels" in sys.argv,
-                                  smoke=smoke))
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_dse", 0.0, f"ERROR:{type(e).__name__}"))
-    shard_path = (bench_shard.OUT_PATH_SMOKE if smoke
-                  else bench_shard.OUT_PATH)
-    try:
-        rows.extend(bench_shard.run(fast=fast or "--kernels" in sys.argv,
-                                    smoke=smoke))
-        print(f"shard records -> {shard_path}")
-    except Exception as e:  # noqa: BLE001
-        traceback.print_exc()
-        rows.append(("bench_shard", 0.0, f"ERROR:{type(e).__name__}"))
+    kw = dict(fast=fast or "--kernels" in sys.argv, smoke=smoke)
+
+    def path(mod, attr="OUT_PATH"):
+        return getattr(mod, attr + "_SMOKE" if smoke else attr)
+
+    # (row name on failure, call, output file announced on success)
+    phases = [(m.__name__.split(".")[-1], m.run, None) for m in mods]
+    for name, fn, out in (
+            ("bench_kernels", bench_kernels.run, path(bench_kernels)),
+            ("bench_dispatch", bench_kernels.run_dispatch,
+             path(bench_kernels, "DISPATCH_PATH")),
+            ("bench_conv", bench_conv.run, path(bench_conv)),
+            ("bench_attn", bench_attn.run, path(bench_attn)),
+            ("bench_serve", bench_serve.run, None),
+            ("bench_faults", bench_faults.run, None),
+            ("bench_obs", bench_obs.run, None),
+            ("bench_dse", bench_dse.run, None),
+            ("bench_shard", bench_shard.run, path(bench_shard))):
+        phases.append((name, functools.partial(fn, **kw), out))
     if mods:
+        phases.append(("cim_energy", roofline.energy_report, None))
+
+    rows = []
+    for name, fn, out in phases:
         try:
-            rows.extend(roofline.energy_report())
-        except Exception:  # noqa: BLE001
+            rows.extend(fn())
+        except Exception as e:  # noqa: BLE001 — reported, then exit 1
             traceback.print_exc()
+            rows.append((name, 0.0, f"ERROR:{type(e).__name__}"))
+            continue
+        if out:
+            print(f"{name} records -> {out}")
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
+    failed = [name for name, _, derived in rows
+              if str(derived).startswith("ERROR")]
+    if failed:
+        sys.exit(f"benchmark phases failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -5,6 +5,13 @@ per-module accuracy allocator (DESIGN.md §16) without forcing JAX/model
 imports on package import.
 """
 
+import os as _os
+
+# Root of the checkout (src/repro/ -> ../..).  Every cache the package
+# keeps on disk lives under it (`.cache/`, `.jax_cache/`, git-ignored).
+CHECKOUT = _os.path.dirname(_os.path.dirname(_os.path.dirname(
+    _os.path.abspath(__file__))))
+
 _LAZY = {
     "autoallocate": ("repro.core.allocate", "autoallocate"),
     "Allocation": ("repro.core.allocate", "Allocation"),

@@ -153,6 +153,14 @@ class KernelEntry:
 _REGISTRY: Dict[str, KernelEntry] = {}
 
 
+class RoutingError(ValueError):
+    """No registered kernel supports the request on this backend.  A
+    subclass of ValueError for the callers that treat every rejection
+    alike; the models layer tells it apart from a geometry rejection
+    (`models/attention._cim_sdpa`) so it is never absorbed by a float
+    fallback."""
+
+
 def register_kernel(entry: KernelEntry) -> KernelEntry:
     if entry.name in _REGISTRY:
         raise ValueError(f"kernel {entry.name!r} already registered")
@@ -176,14 +184,23 @@ register_kernel(KernelEntry(
     name="jnp_lut", modes=("bit_exact",), families=(), backends=(),
     max_bits=MAX_LUT_BITS, oracle="lut_matmul_ref", bound="bit",
     description="pure-jnp LUT gather oracle (validation scale)"))
+# Backends a Pallas entry claims are the ones its kernel is known to
+# build for.  The gather kernels (full-LUT and nibble `jnp.take` over
+# index tensors) are refused by the TPU compiler — Mosaic does not lower
+# the gather — so they claim only the CPU, where they run in interpret
+# mode as the bit-exact hardware-mode reference.  On a TPU, a hardware
+# request for exact/appro42 raises at routing (`RoutingError`).
+INTERPRET_ONLY = ("cpu",)
+
 register_kernel(KernelEntry(
     name="pallas_lut_gather", modes=("hardware",),
-    families=("exact", "appro42"), backends=(), max_bits=8,
+    families=("exact", "appro42"), backends=INTERPRET_ONLY, max_bits=8,
     pallas=True, autotuned=True, oracle="lut_matmul_ref", bound="bit",
     description="Pallas k-sliced LUT-gather kernel (any LUT family)"))
 register_kernel(KernelEntry(
     name="pallas_lut_nibble", modes=("hardware",),
-    families=("exact", "appro42"), backends=(), priority=20, max_bits=8,
+    families=("exact", "appro42"), backends=INTERPRET_ONLY, priority=20,
+    max_bits=8,
     pallas=True, autotuned=True, oracle="lut_matmul_ref", bound="bit",
     predicate=nibble_decomposable,
     description="Pallas nibble-decomposed kernel (4 x 2^{b/2} sub-LUTs; "
@@ -208,7 +225,9 @@ register_kernel(KernelEntry(
 # materialized im2col + GEMM path stays registered at priority 0 as the
 # always-eligible fallback (and the benchmark baseline); the Pallas
 # implicit kernels outrank it when the request and the VMEM footprint
-# model admit them (`plan_conv`).
+# model admit them (`plan_conv`).  The implicit kernels have not been
+# built by the TPU compiler yet (ROADMAP), so they claim only the CPU;
+# on a TPU every conv runs im2col + the routed GEMM kernel.
 register_kernel(KernelEntry(
     name="conv_im2col", op="conv", modes=MODES, families=(), backends=(),
     oracle="im2col + the routed GEMM kernel's oracle", bound="fp32",
@@ -216,25 +235,28 @@ register_kernel(KernelEntry(
                 "(every mode; also the bench_conv.py baseline)"))
 register_kernel(KernelEntry(
     name="pallas_conv_mxu", op="conv", modes=("exact",), families=(),
-    backends=(), priority=10, max_bits=8, pallas=True, autotuned=True,
+    backends=INTERPRET_ONLY, priority=10, max_bits=8, pallas=True,
+    autotuned=True,
     oracle="float conv (lax.conv_general_dilated)", bound="fp32",
     description="implicit-GEMM fused-quantization conv, dequantized MXU "
                 "dot per kernel tap"))
 register_kernel(KernelEntry(
     name="pallas_conv_lut", op="conv", modes=("hardware",),
-    families=("exact", "appro42"), backends=(), priority=10, max_bits=8,
+    families=("exact", "appro42"), backends=INTERPRET_ONLY, priority=10,
+    max_bits=8,
     pallas=True, autotuned=True, oracle="im2col + lut_matmul_ref",
     bound="bit",
     description="implicit-GEMM full-LUT gather conv (k-sliced)"))
 register_kernel(KernelEntry(
     name="pallas_conv_nibble", op="conv", modes=("hardware",),
-    families=("exact", "appro42"), backends=(), priority=20, max_bits=8,
+    families=("exact", "appro42"), backends=INTERPRET_ONLY, priority=20,
+    max_bits=8,
     pallas=True, autotuned=True, oracle="im2col + lut_matmul_ref",
     bound="bit", predicate=nibble_decomposable,
     description="implicit-GEMM nibble sub-LUT conv (4 x 2^{b/2} tables)"))
 register_kernel(KernelEntry(
     name="pallas_conv_log", op="conv", modes=("hardware",),
-    families=("mitchell", "log_our"), backends=(), priority=10,
+    families=("mitchell", "log_our"), backends=INTERPRET_ONLY, priority=10,
     max_bits=16, pallas=True, autotuned=True,
     oracle="im2col + mitchell_matmul_ref", bound="bit",
     description="implicit-GEMM log-domain conv (LoD+shift+OR per tap)"))
@@ -244,6 +266,9 @@ register_kernel(KernelEntry(
 # always-eligible fallback (same tiled numerics, so still bound="bit"
 # against the materialized oracle); the Pallas kernels outrank it when
 # the VMEM footprint and bit-safety predicates admit them (`plan_attn`).
+# The TPU compiler refuses the Pallas kernels' (1, bk) position and
+# validity blocks over (B, Skv) operands (ROADMAP), so they claim only
+# the CPU; on a TPU the integer attention modes run `attn_xla`.
 # Modes: the quantized integer cores only — float/surrogate attention
 # stays on the models-layer `_chunked_attn` path.
 ATTN_MODES = ("exact", "bit_exact", "hardware")
@@ -255,25 +280,28 @@ register_kernel(KernelEntry(
                 "fallback + validation scale)"))
 register_kernel(KernelEntry(
     name="pallas_attn_mxu", op="attn", modes=("exact",), families=(),
-    backends=(), priority=10, max_bits=8, pallas=True, autotuned=True,
+    backends=INTERPRET_ONLY, priority=10, max_bits=8, pallas=True,
+    autotuned=True,
     oracle="attn_materialized", bound="bit",
     description="flash attention, integer-valued f32 MXU dots (exact "
                 "in-kernel baseline; qmax^2*K < 2^24 gated)"))
 register_kernel(KernelEntry(
     name="pallas_attn_lut", op="attn", modes=("hardware",),
-    families=("exact", "appro42"), backends=(), priority=10, max_bits=8,
+    families=("exact", "appro42"), backends=INTERPRET_ONLY, priority=10,
+    max_bits=8,
     pallas=True, autotuned=True, oracle="attn_materialized", bound="bit",
     description="flash attention, k-sliced full-LUT gather QK^T/PV"))
 register_kernel(KernelEntry(
     name="pallas_attn_nibble", op="attn", modes=("hardware",),
-    families=("exact", "appro42"), backends=(), priority=20, max_bits=8,
+    families=("exact", "appro42"), backends=INTERPRET_ONLY, priority=20,
+    max_bits=8,
     pallas=True, autotuned=True, oracle="attn_materialized", bound="bit",
     predicate=nibble_decomposable,
     description="flash attention, nibble sub-LUT QK^T/PV (4 x 2^{b/2} "
                 "tables)"))
 register_kernel(KernelEntry(
     name="pallas_attn_log", op="attn", modes=("hardware",),
-    families=("mitchell", "log_our"), backends=(), priority=10,
+    families=("mitchell", "log_our"), backends=INTERPRET_ONLY, priority=10,
     max_bits=12, pallas=True, autotuned=True,
     oracle="attn_materialized", bound="bit",
     description="flash attention, log-domain QK^T/PV (LoD+shift+OR)"))
@@ -287,7 +315,7 @@ def _select_kernel_cached(family: str, mode: str, bits: int, backend: str,
                and (e.predicate is None
                     or (spec is not None and e.predicate(spec)))]
     if not matches:
-        raise ValueError(
+        raise RoutingError(
             f"no kernel for family={family!r} mode={mode!r} bits={bits} "
             f"backend={backend!r}; registered: "
             f"{sorted(_REGISTRY)}")
@@ -474,7 +502,7 @@ def _conv_entries_cached(family: str, mode: str, bits: int, backend: str,
                and (e.predicate is None
                     or (spec is not None and e.predicate(spec)))]
     if not matches:
-        raise ValueError(
+        raise RoutingError(
             f"no conv kernel for family={family!r} mode={mode!r} "
             f"bits={bits} backend={backend!r}; registered: "
             f"{sorted(e.name for e in _REGISTRY.values() if e.op == 'conv')}")
@@ -741,7 +769,7 @@ def _attn_entries_cached(family: str, mode: str, bits: int, backend: str,
                and (e.predicate is None
                     or (spec is not None and e.predicate(spec)))]
     if not matches:
-        raise ValueError(
+        raise RoutingError(
             f"no attention kernel for family={family!r} mode={mode!r} "
             f"bits={bits} backend={backend!r}; registered: "
             f"{sorted(e.name for e in _REGISTRY.values() if e.op == 'attn')}")
@@ -1636,11 +1664,16 @@ def obs_mac_scale(factor: float):
 
 
 def _obs_dispatch(op: str, gp: "GemmParams", macs: float,
-                  cache_hit: bool) -> None:
+                  cache_hit: bool, kernel: str) -> None:
     _OBS_SINK[0].dispatch(op=op, family=gp.family, mode=gp.mode,
                           bits=gp.bits,
                           macs=macs * _OBS_MAC_SCALE[0],
-                          cache_hit=cache_hit)
+                          cache_hit=cache_hit, kernel=kernel)
+
+
+def _plan_kernel(plan) -> str:
+    """Registry name of the kernel a (mesh) plan routes to."""
+    return (plan.plan if isinstance(plan, MeshPlan) else plan).entry.name
 
 
 # ---------------------------------------------------------------------------
@@ -1862,10 +1895,8 @@ def _conv_forward(gp: GemmParams, plan: ConvPlan, noise_kind: str,
 
 
 def _shard_map(fn, mp: MeshPlan):
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mp.mesh, in_specs=mp.in_specs,
-                     out_specs=mp.out_spec, check_rep=False)
+    return jax.shard_map(fn, mesh=mp.mesh, in_specs=mp.in_specs,
+                         out_specs=mp.out_spec, check_vma=False)
 
 
 def _mesh_forward(gp: GemmParams, mp: MeshPlan, preserve_dtype: bool):
@@ -2219,7 +2250,7 @@ def executable_cache_size() -> int:
 # (plan_gemm -> _exec_key -> executable) into ONE dict hit on a key of
 # cheap hashables — the per-call overhead on top of the jitted
 # executable is a tuple hash + dict get.  Values are (run, stochastic).
-_FAST_CACHE: Dict[Tuple, Tuple[Callable, bool]] = {}
+_FAST_CACHE: Dict[Tuple, Tuple[Callable, bool, str]] = {}
 
 
 def clear_dispatch_caches() -> None:
@@ -2292,10 +2323,10 @@ def cim_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
                 noise_kind, interpret, block, jax.default_backend(),
                 mesh, _canon_spec(x_spec), _canon_spec(w_spec))
         hit = _FAST_CACHE.get(fkey)
-        if _OBS_SINK[0] is not None:
-            _obs_dispatch("gemm", gp, float(m) * k * n, hit is not None)
         if hit is not None:
-            run, stochastic = hit
+            run, stochastic, kernel = hit
+            if _OBS_SINK[0] is not None:
+                _obs_dispatch("gemm", gp, float(m) * k * n, True, kernel)
             return run(x, w, key) if stochastic else run(x, w)
     if gp.mode not in MODES:
         raise ValueError(f"mode {gp.mode!r} not in {MODES}")
@@ -2309,7 +2340,10 @@ def cim_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
         run = _executable_for("cim", gp, plan, stochastic, noise_kind,
                               True, x, w, m, k, n)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, stochastic)
+            _FAST_CACHE[fkey] = (run, stochastic, _plan_kernel(plan))
+        if _OBS_SINK[0] is not None:
+            _obs_dispatch("gemm", gp, float(m) * k * n, False,
+                          _plan_kernel(plan))
         return run(x, w, key) if stochastic else run(x, w)
 
     xf2 = x.reshape((-1, k))
@@ -2405,13 +2439,12 @@ def cim_conv2d(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
                 + autotune.bucket_conv(b, h, w_, c, kh, kw, stride)
                 + (autotune.bucket(n),))
         hit = _FAST_CACHE.get(fkey)
-        if _OBS_SINK[0] is not None:
-            oh_, ow_ = conv_out_hw(h, w_, kh, kw, stride)
-            _obs_dispatch("conv", gp,
-                          float(b) * oh_ * ow_ * kh * kw * c * n,
-                          hit is not None)
+        oh_, ow_ = conv_out_hw(h, w_, kh, kw, stride)
+        macs = float(b) * oh_ * ow_ * kh * kw * c * n
         if hit is not None:
-            run, stochastic = hit
+            run, stochastic, kernel = hit
+            if _OBS_SINK[0] is not None:
+                _obs_dispatch("conv", gp, macs, True, kernel)
             return run(x, w, key) if stochastic else run(x, w)
     if gp.mode not in MODES:
         raise ValueError(f"mode {gp.mode!r} not in {MODES}")
@@ -2432,7 +2465,9 @@ def cim_conv2d(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
         run = _conv_executable_for(gp, plan, stochastic, noise_kind, x, w,
                                    b, h, w_, c, n)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, stochastic)
+            _FAST_CACHE[fkey] = (run, stochastic, _plan_kernel(plan))
+        if _OBS_SINK[0] is not None:
+            _obs_dispatch("conv", gp, macs, False, _plan_kernel(plan))
         return run(x, w, key) if stochastic else run(x, w)
 
     if isinstance(plan, MeshPlan):
@@ -2517,13 +2552,12 @@ def cim_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  jax.default_backend())
                 + autotune.bucket_attn(b, heads, kv_heads, sq, skv, hd))
         hit = _FAST_CACHE.get(fkey)
-        if _OBS_SINK[0] is not None:
-            # QK^T + PV: two Skv-deep dots per (batch, head, query)
-            _obs_dispatch("attn", gp,
-                          2.0 * b * heads * sq * skv * hd,
-                          hit is not None)
+        # QK^T + PV: two Skv-deep dots per (batch, head, query)
+        macs = 2.0 * b * heads * sq * skv * hd
         if hit is not None:
-            run, _ = hit
+            run, _, kernel = hit
+            if _OBS_SINK[0] is not None:
+                _obs_dispatch("attn", gp, macs, True, kernel)
             return run(q, k, v, q_positions, kv_positions, kv_valid)
     plan = plan_attn(gp.family, gp.mode, gp.bits, b, heads, kv_heads, sq,
                      skv, hd, ap, interpret=interpret, block=block,
@@ -2532,7 +2566,9 @@ def cim_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         run = _attn_executable_for(gp, plan, q, k, b, heads, kv_heads,
                                    sq, skv, hd)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, False)
+            _FAST_CACHE[fkey] = (run, False, plan.entry.name)
+        if _OBS_SINK[0] is not None:
+            _obs_dispatch("attn", gp, macs, False, plan.entry.name)
         return run(q, k, v, q_positions, kv_positions, kv_valid)
     return _build_attn_executable(gp, plan)(q, k, v, q_positions,
                                             kv_positions, kv_valid)
@@ -2592,11 +2628,11 @@ def model_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
                 noise_kind, apply, jax.default_backend(),
                 mesh, _canon_spec(x_spec), _canon_spec(w_spec))
         hit = _FAST_CACHE.get(fkey)
-        if _OBS_SINK[0] is not None:
-            _obs_dispatch("model_gemm", gp, float(m) * k * n,
-                          hit is not None)
         if hit is not None:
-            run, stochastic = hit
+            run, stochastic, kernel = hit
+            if _OBS_SINK[0] is not None:
+                _obs_dispatch("model_gemm", gp, float(m) * k * n, True,
+                              kernel)
             return run(x, w, key) if stochastic else run(x, w)
     mode = gp.mode if apply else "exact"
     plan = plan_gemm(gp.family, mode, gp.bits, m, k, n,
@@ -2608,7 +2644,10 @@ def model_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
         run = _executable_for("model", gp, plan, stochastic, noise_kind,
                               apply, x, w, m, k, n)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, stochastic)
+            _FAST_CACHE[fkey] = (run, stochastic, _plan_kernel(plan))
+        if _OBS_SINK[0] is not None:
+            _obs_dispatch("model_gemm", gp, float(m) * k * n, False,
+                          _plan_kernel(plan))
         return run(x, w, key) if stochastic else run(x, w)
 
     if isinstance(plan, MeshPlan):

@@ -6,12 +6,15 @@ VMEM footprint, the operand shapes and the backend, so the dispatcher
 asks this module instead of hard-coding one:
 
   * on TPU, `best_block` sweeps a small candidate set, times each
-    configuration end-to-end (compile excluded via a warmup call) and
+    configuration end-to-end (compiled ahead of time, then warmed) and
     persists the winner to a JSON cache on disk keyed by
-    (kernel, bits, bucketed shape, backend);
-  * off TPU (this container: CPU interpret mode, where timings are
-    meaningless) it returns a shape-clipped heuristic default without
-    touching the disk cache;
+    (kernel, bits, bucketed shape, backend).  The sweep always runs on
+    concrete arrays, even when a plan is first resolved while a jitted
+    step is being traced; a candidate the compiler refuses is logged,
+    and a sweep in which every candidate is refused raises;
+  * off TPU (CPU interpret mode, where timings are meaningless) it
+    returns a shape-clipped heuristic default without touching the
+    disk cache;
   * tests inject a fake `measure` callable and a tmp `cache_file` to
     exercise the sweep + persistence logic deterministically.
 
@@ -22,6 +25,7 @@ whole family of nearby GEMMs — the cache stays tiny (a few dozen rows).
 from __future__ import annotations
 
 import json
+import logging
 import os
 import threading
 from typing import Callable, Dict, List, Optional, Tuple
@@ -32,27 +36,28 @@ Block = Tuple[int, int, int]
 # with, now demoted to sweep seeds / off-TPU heuristics.  The candidate
 # sets stay small on purpose: an autotune sweep runs once per bucketed
 # shape and must not dominate the first-call latency.
+#
+# Every block a TPU kernel can be given obeys Mosaic's tiling rule: the
+# last two dims of each BlockSpec are multiples of (8, 128) or span the
+# whole (padded) array.  The x tile is (bm, bk) and the w tile (bk, bn),
+# so bm is a multiple of 8 and bk, bn multiples of 128; `_clip_block`
+# only ever shrinks a dim to the bucketed extent, which the kernels pad
+# the operand to — a full-dimension block.  The gather kernels
+# (pallas_lut_gather / pallas_lut_nibble) run only in interpret mode
+# (Mosaic does not lower their `jnp.take`), so their blocks are CPU
+# heuristics with no sweep.
 DEFAULT_BLOCKS: Dict[str, Block] = {
     "pallas_lut_gather": (32, 32, 128),
     "pallas_lut_nibble": (32, 64, 128),
-    "pallas_log": (32, 32, 32),
+    "pallas_log": (8, 128, 128),
     "pallas_fused_surrogate": (128, 128, 128),
 }
 
 _CANDIDATES: Dict[str, List[Block]] = {
-    # gather-bound: bn rides the 128-lane dimension; the live index
-    # tensor is bounded by the kernel's k_slice, so bk trades HBM
-    # re-fetches against VMEM operand footprint
-    "pallas_lut_gather": [(16, 32, 128), (32, 32, 128), (32, 64, 128),
-                          (64, 32, 128), (32, 32, 256)],
-    # sub-LUTs are 4 KiB instead of 256 KiB, so the candidate set skews
-    # to larger operand tiles than the full-LUT gather kernel
-    "pallas_lut_nibble": [(32, 32, 128), (32, 64, 128), (64, 64, 128),
-                          (64, 128, 128), (32, 64, 256)],
-    # VPU select/shift chains materialize (bm, bk, bn) int32 temporaries;
-    # keep ~8 of them under the VMEM budget
-    "pallas_log": [(16, 32, 64), (32, 32, 32), (32, 32, 64),
-                   (64, 32, 32), (32, 64, 32)],
+    # VPU select/shift chains materialize (bm, bk, bn) int32
+    # temporaries: one (8, 128) vreg row per bm-row, so bm stays small
+    "pallas_log": [(8, 128, 128), (16, 128, 128), (32, 128, 128),
+                   (8, 256, 128), (8, 128, 256)],
     # MXU-bound: native 128x128 systolic tiles, bk trades VMEM for
     # fewer accumulator flushes
     "pallas_fused_surrogate": [(128, 128, 128), (128, 256, 128),
@@ -87,16 +92,17 @@ _CONV_CANDIDATES: Dict[str, List[Block]] = {
                         (8, 16, 32)],
 }
 
+_LOG = logging.getLogger(__name__)
 _ENV_CACHE = "OPENACM_AUTOTUNE_CACHE"
 _mem_cache: Dict[str, Block] = {}
 _lock = threading.Lock()
 
 
 def cache_path() -> str:
+    from repro import CHECKOUT
+
     return os.environ.get(
-        _ENV_CACHE,
-        os.path.join(os.path.expanduser("~"), ".cache", "openacm",
-                     "autotune.json"))
+        _ENV_CACHE, os.path.join(CHECKOUT, ".cache", "autotune.json"))
 
 
 def bucket(v: int) -> int:
@@ -238,13 +244,19 @@ def _resolve(key: str, candidates: List[Block], fallback: Block,
         _obs_autotune(key, "heuristic")
         return fallback
 
-    timings = []
+    timings, refused = [], []
     for block in candidates:
         try:
             timings.append((measure(block), block))
-        except Exception:  # noqa: BLE001 — a block can exceed VMEM
-            continue
-    block = min(timings)[1] if timings else fallback
+        except Exception as e:  # noqa: BLE001 — compiler/VMEM refusals
+            _LOG.warning("autotune %s: block %s refused: %s: %s", key,
+                         block, type(e).__name__, e)
+            refused.append((block, f"{type(e).__name__}: {e}"))
+    if not timings:
+        raise RuntimeError(
+            f"autotune {key}: every candidate block was refused: "
+            + "; ".join(f"{b}: {msg[:300]}" for b, msg in refused))
+    block = min(timings)[1]
     with _lock:
         _mem_cache[key] = block
         # merge-on-save: re-load under the lock so concurrent tuners
@@ -263,14 +275,16 @@ def best_block(kernel: str, bits: int, m: int, k: int, n: int,
     """Resolve the block triple for one kernel/shape/backend.
 
     `measure(block) -> seconds` runs the sweep when provided (tests) or
-    when the backend is a real TPU (production); anything else gets the
-    clipped heuristic default, cached in memory only.
+    when the backend is the TPU this process runs on (production);
+    anything else — including a TPU plan resolved on a host without
+    one, e.g. to compile for a described chip — gets the clipped
+    heuristic default, cached in memory only.
     """
-    if backend is None:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-    if measure is None and backend == "tpu":
+    here = jax.default_backend()
+    backend = backend or here
+    if measure is None and backend == here == "tpu":
         measure = _default_measure(kernel, bits, m, k, n)
     return _resolve(cache_key(kernel, bits, m, k, n, backend),
                     candidate_blocks(kernel, m, k, n),
@@ -327,14 +341,13 @@ def best_conv_block(kernel: str, bits: int, b: int, h: int, w: int, c: int,
                     measure: Optional[Callable[[Block], float]] = None,
                     cache_file: Optional[str] = None) -> Block:
     """`best_block` for the implicit-GEMM conv kernels: same disk cache,
-    same corrupt-cache hardening, conv-shaped key and candidates."""
+    same corrupt-cache hardening, conv-shaped key and candidates.  The
+    conv kernels run only in interpret mode (core/approx_gemm.py), so
+    only a caller-supplied `measure` sweeps."""
     if backend is None:
         import jax
 
         backend = jax.default_backend()
-    if measure is None and backend == "tpu":
-        measure = _default_conv_measure(kernel, bits, b, h, w, c, n,
-                                        kh, kw, stride)
     return _resolve(conv_cache_key(kernel, bits, b, h, w, c, n, kh, kw,
                                    stride, backend),
                     candidate_conv_blocks(kernel, b, c, n),
@@ -421,14 +434,13 @@ def best_attn_block(kernel: str, bits: int, b: int, heads: int,
                     measure: Optional[Callable[[AttnBlock], float]] = None,
                     cache_file: Optional[str] = None) -> AttnBlock:
     """`best_block` for the flash-attention kernels: same disk cache,
-    same corrupt-cache hardening, attention-shaped key and candidates."""
+    same corrupt-cache hardening, attention-shaped key and candidates.
+    The attention kernels run only in interpret mode
+    (core/approx_gemm.py), so only a caller-supplied `measure` sweeps."""
     if backend is None:
         import jax
 
         backend = jax.default_backend()
-    if measure is None and backend == "tpu":
-        measure = _default_attn_measure(kernel, bits, b, heads, kv_heads,
-                                        sq, skv, head_dim)
     return _resolve(attn_cache_key(kernel, bits, b, heads, kv_heads, sq,
                                    skv, head_dim, backend),
                     candidate_attn_blocks(kernel, sq, skv),
@@ -436,157 +448,52 @@ def best_attn_block(kernel: str, bits: int, b: int, heads: int,
                     cache_file)
 
 
-def _default_attn_measure(kernel: str, bits: int, b: int, heads: int,
-                          kv_heads: int, sq: int, skv: int,
-                          head_dim: int) -> Callable[[AttnBlock], float]:
-    """Wall-clock measure for the real (non-interpret) attention kernels."""
-    import time
-
-    import jax
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(
-        rng.standard_normal((b, heads, sq, head_dim)).astype(np.float32))
-    k = jnp.asarray(
-        rng.standard_normal((b, kv_heads, skv, head_dim)).astype(np.float32))
-    v = jnp.asarray(
-        rng.standard_normal((b, kv_heads, skv, head_dim)).astype(np.float32))
-    qpos = jnp.broadcast_to(jnp.arange(sq, dtype=jnp.int32)[None], (b, sq))
-    kpos = jnp.broadcast_to(jnp.arange(skv, dtype=jnp.int32)[None], (b, skv))
-    kval = jnp.ones((b, skv), jnp.int32)
-
-    def run(block: AttnBlock):
-        from repro.core.multipliers import MultiplierSpec
-        from repro.kernels import ops
-
-        if kernel == "pallas_attn_mxu":
-            return ops.cim_attn_fused(q, k, v, qpos, kpos, kval,
-                                      path="mxu", bits=bits, block=block,
-                                      interpret=False)
-        if kernel == "pallas_attn_lut":
-            spec = MultiplierSpec("appro42", bits, True)
-            return ops.cim_attn_fused(q, k, v, qpos, kpos, kval,
-                                      path="lut", spec=spec, bits=bits,
-                                      block=block, interpret=False)
-        if kernel == "pallas_attn_nibble":
-            spec = MultiplierSpec("exact", bits, True)
-            return ops.cim_attn_fused(q, k, v, qpos, kpos, kval,
-                                      path="nibble", spec=spec, bits=bits,
-                                      block=block, interpret=False)
-        if kernel == "pallas_attn_log":
-            return ops.cim_attn_fused(q, k, v, qpos, kpos, kval,
-                                      path="log", bits=bits, block=block,
-                                      interpret=False)
-        raise ValueError(f"no attn measure recipe for kernel {kernel!r}")
-
-    def measure(block: AttnBlock) -> float:
-        jax.block_until_ready(run(block))          # compile + warm
-        reps = 3
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            jax.block_until_ready(run(block))
-        return (time.perf_counter() - t0) / reps
-
-    return measure
-
-
 def _default_measure(kernel: str, bits: int, m: int, k: int,
                      n: int) -> Callable[[Block], float]:
-    """Wall-clock measure for the real (non-interpret) kernels."""
+    """Wall-clock measure of the fused (f32 in, f32 out) kernel the
+    dispatch engine serves.  Plans are often resolved while a jitted
+    step is being traced, where a nested jitted call would be staged
+    into that trace (it returns a tracer, and `block_until_ready` would
+    time nothing).  So the operands are made concrete under
+    `ensure_compile_time_eval`, and each candidate is compiled ahead of
+    time and its executable called directly: neither joins the
+    enclosing trace.  Compile time is excluded."""
+    import functools
     import time
 
     import jax
     import numpy as np
 
-    rng = np.random.default_rng(0)
-    import jax.numpy as jnp
+    from repro.kernels import cim_gemm, ops
 
-    xq = jnp.asarray(rng.integers(-127, 128, (m, k), dtype=np.int8))
-    wq = jnp.asarray(rng.integers(-127, 128, (k, n), dtype=np.int8))
-
-    def run(block: Block):
-        from repro.kernels import ops
-
-        if kernel == "pallas_lut_gather":
-            from repro.core.multipliers import MultiplierSpec
-
-            spec = MultiplierSpec("appro42", bits, True)
-            return ops.approx_matmul_bit_exact(xq, wq, spec, block=block,
-                                               interpret=False)
-        if kernel == "pallas_lut_nibble":
-            from repro.core.multipliers import MultiplierSpec
-
-            spec = MultiplierSpec("exact", bits, True)
-            return ops.nibble_matmul_bit_exact(xq, wq, spec, block=block,
-                                               interpret=False)
-        if kernel == "pallas_log":
-            return ops.log_matmul(xq, wq, bits=bits, block=block,
-                                  interpret=False)
-        if kernel == "pallas_fused_surrogate":
-            return ops.cim_gemm_core(xq, wq, need_sq=True, block=block,
-                                     interpret=False)[0]
+    if kernel not in ("pallas_log", "pallas_fused_surrogate"):
         raise ValueError(f"no measure recipe for kernel {kernel!r}")
 
+    def run(block: Block, x, w, eps):
+        if kernel == "pallas_log":
+            return ops.log_matmul_fused(x, w, bits=bits, block=block,
+                                        interpret=False)
+        return cim_gemm.cim_gemm_fused(x, w, eps, 0.0, 0.0, 1e-3,
+                                       bits=bits, block=block,
+                                       interpret=False)
+
+    @functools.lru_cache(maxsize=1)
+    def operands():
+        rng = np.random.default_rng(0)
+        with jax.ensure_compile_time_eval():
+            return tuple(jax.device_put(a) for a in (
+                rng.standard_normal((m, k)).astype(np.float32),
+                rng.standard_normal((k, n)).astype(np.float32),
+                np.zeros((m, n), np.float32)))
+
     def measure(block: Block) -> float:
-        jax.block_until_ready(run(block))          # compile + warm
+        args = operands()
+        exe = jax.jit(functools.partial(run, block)).lower(*args).compile()
+        jax.block_until_ready(exe(*args))              # warm
         reps = 3
         t0 = time.perf_counter()
         for _ in range(reps):
-            jax.block_until_ready(run(block))
-        return (time.perf_counter() - t0) / reps
-
-    return measure
-
-
-def _default_conv_measure(kernel: str, bits: int, b: int, h: int, w: int,
-                          c: int, n: int, kh: int, kw: int,
-                          stride: int) -> Callable[[Block], float]:
-    """Wall-clock measure for the real (non-interpret) conv kernels."""
-    import time
-
-    import jax
-    import numpy as np
-
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((b, h, w, c)).astype(np.float32))
-    w2 = jnp.asarray(
-        rng.standard_normal((kh * kw * c, n)).astype(np.float32))
-
-    def run(block: Block):
-        from repro.core.multipliers import MultiplierSpec
-        from repro.kernels import ops
-
-        if kernel == "pallas_conv_mxu":
-            return ops.conv2d_mxu_fused(x, w2, bits=bits, kh=kh, kw=kw,
-                                        stride=stride, block=block,
-                                        interpret=False)
-        if kernel == "pallas_conv_lut":
-            spec = MultiplierSpec("appro42", bits, True)
-            return ops.conv2d_lut_fused(x, w2, spec, kh=kh, kw=kw,
-                                        stride=stride, block=block,
-                                        interpret=False)
-        if kernel == "pallas_conv_nibble":
-            spec = MultiplierSpec("exact", bits, True)
-            return ops.conv2d_nibble_fused(x, w2, spec, kh=kh, kw=kw,
-                                           stride=stride, block=block,
-                                           interpret=False)
-        if kernel == "pallas_conv_log":
-            return ops.conv2d_log_fused(x, w2, bits=bits, compensated=True,
-                                        kh=kh, kw=kw, stride=stride,
-                                        block=block, interpret=False)
-        raise ValueError(f"no conv measure recipe for kernel {kernel!r}")
-
-    def measure(block: Block) -> float:
-        jax.block_until_ready(run(block))          # compile + warm
-        reps = 3
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            jax.block_until_ready(run(block))
+            jax.block_until_ready(exe(*args))
         return (time.perf_counter() - t0) / reps
 
     return measure

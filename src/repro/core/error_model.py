@@ -192,10 +192,10 @@ _METRIC_FIELDS = tuple(f.name for f in dataclasses.fields(ErrorMetrics))
 
 
 def cache_path() -> str:
+    from repro import CHECKOUT
+
     return os.environ.get(
-        _ENV_CACHE,
-        os.path.join(os.path.expanduser("~"), ".cache", "openacm",
-                     "characterize.json"))
+        _ENV_CACHE, os.path.join(CHECKOUT, ".cache", "characterize.json"))
 
 
 def clear_memory_cache() -> None:
@@ -344,33 +344,26 @@ def _products_fn(spec_keys: Tuple[Tuple, ...], mesh):
              for s in specs])
 
     if mesh is not None:
-        try:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-            from repro.parallel.sharding import batch_axes
+        from repro.parallel.sharding import batch_axes
 
-            axes = batch_axes(mesh)
-            entry = axes if len(axes) > 1 else (axes[0] if axes else None)
-            if entry is not None:
-                sharded = shard_map(
-                    f, mesh=mesh, in_specs=(P(entry), P(entry)),
-                    out_specs=P(None, entry), check_rep=False)
-                return jax.jit(sharded)
-        except Exception:  # noqa: BLE001 — mesh is an optimization only
-            pass
+        axes = batch_axes(mesh)
+        entry = axes if len(axes) > 1 else (axes[0] if axes else None)
+        if entry is not None:
+            sharded = jax.shard_map(
+                f, mesh=mesh, in_specs=(P(entry), P(entry)),
+                out_specs=P(None, entry), check_vma=False)
+            return jax.jit(sharded)
     return jax.jit(f)
 
 
 def _mesh_divides(mesh, n: int) -> bool:
     if mesh is None:
         return False
-    try:
-        from repro.parallel.sharding import batch_axes
+    from repro.parallel.sharding import batch_axes
 
-        return bool(batch_axes(mesh, n))
-    except Exception:  # noqa: BLE001
-        return False
+    return bool(batch_axes(mesh, n))
 
 
 def characterize_batch(specs: Sequence[MultiplierSpec],

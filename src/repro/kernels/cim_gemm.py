@@ -39,7 +39,9 @@ def _kernel(x_ref, w_ref, d_ref, sq_ref, accd_ref, accs_ref, *, need_sq):
 
     a = x_ref[...]
     b = w_ref[...]
-    accd_ref[...] += jax.lax.dot(a.astype(jnp.float32), b.astype(jnp.float32),
+    # int8 x int8 -> int32 on the MXU (Mosaic refuses an int32
+    # accumulator over float operands)
+    accd_ref[...] += jax.lax.dot(a.astype(jnp.int8), b.astype(jnp.int8),
                                  preferred_element_type=jnp.int32)
     if need_sq:
         af = a.astype(jnp.float32)
@@ -117,10 +119,13 @@ def _fused_kernel(sx_ref, x_ref, w_ref, sw_ref, eps_ref, d_ref, accd_ref,
             accs_ref[...] = jnp.zeros_like(accs_ref)
 
     qmax = (1 << (bits - 1)) - 1
-    af = _quantize_tile(x_ref[...], sx_ref[0, 0], qmax).astype(jnp.float32)
-    bf = _quantize_tile(w_ref[...], sw_ref[...], qmax).astype(jnp.float32)
-    accd_ref[...] += jax.lax.dot(af, bf, preferred_element_type=jnp.int32)
+    aq = _quantize_tile(x_ref[...], sx_ref[0, 0], qmax)
+    bq = _quantize_tile(w_ref[...], sw_ref[...], qmax)
+    accd_ref[...] += jax.lax.dot(aq.astype(jnp.int8), bq.astype(jnp.int8),
+                                 preferred_element_type=jnp.int32)
     if stochastic and c1 > 0.0:
+        af = aq.astype(jnp.float32)
+        bf = bq.astype(jnp.float32)
         accs_ref[...] += jax.lax.dot(af * af, bf * bf,
                                      preferred_element_type=jnp.float32)
 

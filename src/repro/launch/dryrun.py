@@ -128,7 +128,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         lambda s: batch_sharding(mesh, len(s.shape), s.shape[0]), specs)
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = adamw.AdamWConfig(state_bits=8)
             oshape = jax.eval_shape(lambda p: adamw.init(p, opt_cfg), pshape)
@@ -189,6 +189,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
         "cim": cim, "tag": tag,
         "skipped": False,
         "n_devices": n_dev,
+        # the devices the HLO was compiled for (benchmarks/roofline.py
+        # takes its peaks from this, and refuses kinds it has none for)
+        "device_kind": jax.devices()[0].device_kind,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),
         "memory": {
             "args_bytes": ma.argument_size_in_bytes,

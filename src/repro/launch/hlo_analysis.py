@@ -27,12 +27,10 @@ from typing import Dict, List, Optional, Tuple
 
 
 def xla_cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions: newer releases
-    return one dict, older ones a one-element list of dicts."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()`` (one dict; empty when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
+
 
 _DT_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

@@ -3,13 +3,21 @@ synthetic Poisson arrival workload (DESIGN.md §10).
 
 `python -m repro.launch.serve --arch qwen3-1.7b --slots 4 --n-requests 16`
 
+`--full` serves the architecture at its published widths (random bf16
+weights drawn from `--seed`; qwen3-1.7b takes about 4 GB) instead of
+the 64-wide smoke config, e.g. on one TPU v5e:
+
+    python -m repro.launch.serve --full --max-len 512 \
+        --prompt-len 64 256 --max-new 16 32 --n-requests 8
+
 Builds the per-tier slot-pool engine (serving/engine.py) over the DSE
 accuracy ladder (serving/tiers.py), pre-warms every (tier x bucket)
 executable, serves the workload, and prints throughput / latency /
 retrace stats.  `--static` degrades admission to lockstep batching (the
-baseline bench_serve.py quantifies against).  Smoke configs on CPU; the
-same jitted prefill/decode functions are what the dry-run lowers for
-the production mesh.
+baseline bench_serve.py quantifies against).  The smoke config is the
+CPU default; the same jitted prefill/decode functions are what the
+dry-run lowers for the production mesh.  Compiled executables persist
+in JAX's compilation cache (`launch/compile_cache.py`).
 """
 
 from __future__ import annotations
@@ -17,6 +25,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import (EngineStats, RealClock, build_engine,
                            build_tiers, poisson_workload,
                            servable_archs)
@@ -26,6 +35,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b",
                     choices=servable_archs())
+    ap.add_argument("--full", action="store_true",
+                    help="published widths instead of the smoke config")
     ap.add_argument("--slots", type=int, default=4,
                     help="slot-pool size per accuracy tier")
     ap.add_argument("--max-len", type=int, default=96)
@@ -132,10 +143,13 @@ def main():
 
         telemetry = EngineTelemetry()
 
-    cfg = get_config(args.arch, smoke=True)
+    use_compile_cache()
+    cfg = get_config(args.arch, smoke=not args.full)
     tiers = build_tiers(mode=args.mode)
     pmax = max(args.prompt_len)
-    pbkts = tuple(sorted({b for b in (8, 16) if b < pmax} | {pmax}))
+    pmin = min(args.prompt_len)
+    pbkts = tuple(sorted({b for b in (8, 16, 32, 64, 128, 256)
+                          if pmin <= b < pmax} | {pmax}))
     engine = build_engine(
         cfg, tiers=tiers, slots_per_tier=args.slots, max_len=args.max_len,
         prompt_buckets=pbkts,

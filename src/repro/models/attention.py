@@ -225,13 +225,16 @@ def _cim_sdpa(q, k, v, p, *, causal, window, qpos, kpos, kval):
     kpos (B, Skv) int32 positions, kval (B, Skv) validity.  Returns the
     f32 attention output, or None when the dispatch engine rejects the
     geometry (the caller keeps the float path — the engine raising is
-    the documented fallback contract, not an error).
+    the documented fallback contract, not an error).  A routing refusal
+    (`RoutingError`: no kernel serves the request on this backend) is
+    not a geometry rejection and propagates.
 
     Per-head tier allocation (``p.attn_heads``: one family name per q
     head): K/V expand to the per-q-head MHA layout — bit-consistent with
     the grouped run because quantization scales are per-head — then each
     family's head subset runs one fused call and scatters back."""
-    from repro.core.approx_gemm import GemmParams, cim_attention
+    from repro.core.approx_gemm import (GemmParams, RoutingError,
+                                        cim_attention)
 
     def gp_for(family):
         # per_token is a linear-layer activation-row contract; attention
@@ -262,6 +265,8 @@ def _cim_sdpa(q, k, v, p, *, causal, window, qpos, kpos, kval):
                               gp_for(fam), **kw)
             out = out.at[:, :, idx].set(o)
         return out
+    except RoutingError:
+        raise
     except ValueError:
         return None                    # unsupported geometry: float path
 

@@ -38,16 +38,12 @@ jax.tree_util.register_pytree_node(
 
 
 def _ambient_mesh():
-    """The mesh of an enclosing ``with mesh:`` block, or None.  The
-    single home of the thread_resources probe (used by both the GSPMD
-    constraint path `wsc` and the §11 mesh dispatch routing)."""
-    try:
-        from jax.interpreters.pxla import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
+    """The (abstract) mesh installed by an enclosing
+    ``jax.set_mesh(mesh)`` block, or None.  The single home of the
+    ambient-mesh probe (used by both the GSPMD constraint path `wsc`
+    and the §11 mesh dispatch routing); valid inside a jit trace."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def wsc(x, spec: Tuple):
@@ -57,16 +53,12 @@ def wsc(x, spec: Tuple):
     mesh = _ambient_mesh()
     if mesh is None:
         return x
-    try:
-        from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding
 
-        from repro.parallel.sharding import logical_to_spec
+    from repro.parallel.sharding import logical_to_spec
 
-        resolved = logical_to_spec(spec, x.shape, mesh)
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, resolved))
-    except Exception:
-        return x
+    resolved = logical_to_spec(spec, x.shape, mesh)
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, resolved))
 
 
 def fsdp_gather(w: Param):

@@ -43,7 +43,7 @@ class MacCapture:
         self.total = 0.0
 
     def dispatch(self, op: str, family: str, mode: str, bits: int,
-                 macs: float, cache_hit: bool) -> None:
+                 macs: float, cache_hit: bool, kernel: str = "") -> None:
         key = (family, int(bits))
         self.by_family[key] = self.by_family.get(key, 0.0) + macs
         self.by_op[op] = self.by_op.get(op, 0.0) + macs

@@ -52,6 +52,9 @@ class EngineTelemetry:
         self.dispatch_calls = r.counter(
             "repro_dispatch_calls_total",
             "dispatch-frontend invocations (eager calls + jit traces)")
+        self.kernel_calls = r.counter(
+            "repro_dispatch_kernel_calls_total",
+            "dispatch-frontend invocations by routed registry kernel")
         self.dispatch_macs = r.counter(
             "repro_dispatch_macs_total",
             "MACs announced at dispatch boundaries")
@@ -142,11 +145,13 @@ class EngineTelemetry:
 
     # -- dispatch sink protocol (approx_gemm / autotune) -------------------
     def dispatch(self, op: str, family: str, mode: str, bits: int,
-                 macs: float, cache_hit: bool) -> None:
+                 macs: float, cache_hit: bool, kernel: str = "") -> None:
         labels = {"op": op, "family": family, "mode": mode,
                   "bits": bits, "cache": "hit" if cache_hit else "miss"}
         self.dispatch_calls.inc(1, **labels)
         self.dispatch_macs.inc(macs, op=op, family=family, bits=bits)
+        if kernel:
+            self.kernel_calls.inc(1, kernel=kernel)
 
     def retrace(self) -> None:
         self.retraces.inc(1)

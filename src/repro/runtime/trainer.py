@@ -47,6 +47,9 @@ class TrainerConfig:
 class Trainer:
     def __init__(self, model: LM, opt_cfg: adamw.AdamWConfig, mesh,
                  tcfg: TrainerConfig, data: Optional[TokenStream] = None):
+        from repro.launch.mesh import require_auto_axes
+
+        require_auto_axes(mesh)
         self.model = model
         self.opt_cfg = opt_cfg
         self.mesh = mesh
@@ -118,7 +121,7 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def init_state(self):
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             params = self._init_fn(jax.random.PRNGKey(self.tcfg.seed))
             opt_state = self._opt_init(params)
         return params, opt_state
@@ -154,7 +157,7 @@ class Trainer:
         start, params, opt_state = self.try_resume(params, opt_state)
         losses = []
         key = jax.random.PRNGKey(self.tcfg.seed + 17)
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             for step in range(start, self.tcfg.steps):
                 t0 = time.perf_counter()
                 tokens = jnp.asarray(self.data.next_batch())

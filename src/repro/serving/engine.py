@@ -253,11 +253,13 @@ class LMLaneBackend:
                                      per_slot=True)
         self._tok_shard = self._pos_shard = None
         if mesh is not None:
+            from repro.launch.mesh import require_auto_axes
             from repro.parallel.sharding import (DECODE_RULES,
                                                  batch_sharding,
                                                  cache_shardings,
                                                  param_shardings)
 
+            require_auto_axes(mesh)
             # weights TP-sharded per DECODE_RULES (no ZeRO-3 at serve
             # time), slots on the data axes; placing params is idempotent
             # across the lanes sharing them
@@ -313,7 +315,9 @@ class LMLaneBackend:
         cim_linear sees the mesh and routes integer-mode matmuls
         through the shard_map dispatch path (DESIGN.md §11)."""
         if self.mesh is not None:
-            return self.mesh
+            import jax
+
+            return jax.set_mesh(self.mesh)
         from contextlib import nullcontext
 
         return nullcontext()

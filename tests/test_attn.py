@@ -195,14 +195,20 @@ def test_use_cim_attn_gates():
 
 
 def test_cim_sdpa_falls_back_on_unsupported_geometry():
-    # 16-bit operands: no registered attention kernel -> the helper
-    # returns None and the caller keeps the float path
-    p = CiMParams(mode="hardware", family="appro42", bits=16, attn=True)
+    # 12-bit exact: qmax^2 * head_dim overflows the f32-exact MXU
+    # accumulator, so the bit-safety predicate rejects every entry (a
+    # geometry rejection) -> the helper returns None and the caller
+    # keeps the float path
     q, k, v = _ops(seed=13)
     qpos, kpos, kval = _full_pos(B, SQ, SKV)
-    out = _cim_sdpa(q, k, v, p, causal=True, window=None,
-                    qpos=qpos, kpos=kpos, kval=kval)
-    assert out is None
+    kw = dict(causal=True, window=None, qpos=qpos, kpos=kpos, kval=kval)
+    p = CiMParams(mode="exact", family="exact", bits=12, attn=True)
+    assert _cim_sdpa(q, k, v, p, **kw) is None
+    # 16-bit appro42 hardware: no registered kernel serves the request
+    # at all (a routing refusal) -> raises, never a silent float path
+    p = CiMParams(mode="hardware", family="appro42", bits=16, attn=True)
+    with pytest.raises(approx_gemm.RoutingError, match="registered"):
+        _cim_sdpa(q, k, v, p, **kw)
 
 
 def test_cim_sdpa_per_head_tiers_match_per_family_runs():
@@ -309,6 +315,11 @@ def test_attn_cached_matches_uncached():
 
 
 def test_attn_autotune_sweep_persists_and_caches(tmp_path):
+    # the attention kernels build only in interpret mode (the TPU
+    # compiler refuses their (1, bk) position blocks), so their sweep is
+    # keyed to the CPU; a caller-supplied measure still drives it
+    entries = {e.name: e for e in approx_gemm.registered_kernels()}
+    assert entries["pallas_attn_lut"].backends == ("cpu",)
     cache = os.path.join(tmp_path, "tune.json")
     calls = []
 
@@ -319,7 +330,7 @@ def test_attn_autotune_sweep_persists_and_caches(tmp_path):
 
     autotune.clear_memory_cache()
     best = autotune.best_attn_block("pallas_attn_lut", 8, 4, 8, 4, 512,
-                                    512, 64, backend="tpu",
+                                    512, 64, backend="cpu",
                                     measure=fake_measure, cache_file=cache)
     assert best == (32, 128)
     assert len(calls) == len(
@@ -327,7 +338,7 @@ def test_attn_autotune_sweep_persists_and_caches(tmp_path):
     autotune.clear_memory_cache()
     calls.clear()
     again = autotune.best_attn_block("pallas_attn_lut", 8, 4, 8, 4, 512,
-                                     512, 64, backend="tpu",
+                                     512, 64, backend="cpu",
                                      measure=fake_measure, cache_file=cache)
     assert again == best and not calls
 
@@ -343,7 +354,7 @@ def test_attn_autotune_corrupt_cache_hardening(tmp_path, garbage):
         fh.write(garbage)
     autotune.clear_memory_cache()
     best = autotune.best_attn_block("pallas_attn_log", 8, 2, 4, 2, 64,
-                                    64, 32, backend="tpu",
+                                    64, 32, backend="cpu",
                                     measure=lambda blk: float(sum(blk)),
                                     cache_file=cache)
     assert best in autotune.candidate_attn_blocks("pallas_attn_log", 64,

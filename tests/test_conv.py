@@ -309,6 +309,11 @@ def test_conv_cached_matches_uncached():
 
 
 def test_conv_autotune_sweep_persists_and_caches(tmp_path):
+    # the implicit-GEMM conv kernels have not been built by the TPU
+    # compiler, so they claim (and their sweep is keyed to) the CPU; a
+    # caller-supplied measure still drives it
+    entries = {e.name: e for e in approx_gemm.registered_kernels()}
+    assert entries["pallas_conv_nibble"].backends == ("cpu",)
     cache = os.path.join(tmp_path, "tune.json")
     calls = []
 
@@ -319,7 +324,7 @@ def test_conv_autotune_sweep_persists_and_caches(tmp_path):
 
     autotune.clear_memory_cache()
     best = autotune.best_conv_block("pallas_conv_nibble", 8, 64, 16, 16,
-                                    64, 128, backend="tpu",
+                                    64, 128, backend="cpu",
                                     measure=fake_measure, cache_file=cache)
     assert best == (8, 64, 128)
     assert len(calls) == len(
@@ -328,7 +333,7 @@ def test_conv_autotune_sweep_persists_and_caches(tmp_path):
     autotune.clear_memory_cache()
     calls.clear()
     again = autotune.best_conv_block("pallas_conv_nibble", 8, 64, 16, 16,
-                                     64, 128, backend="tpu",
+                                     64, 128, backend="cpu",
                                      measure=fake_measure, cache_file=cache)
     assert again == best and not calls
 
@@ -342,7 +347,7 @@ def test_conv_autotune_corrupt_cache_hardening(tmp_path, garbage):
         fh.write(garbage)
     autotune.clear_memory_cache()
     best = autotune.best_conv_block("pallas_conv_log", 8, 16, 16, 16, 16,
-                                    32, backend="tpu",
+                                    32, backend="cpu",
                                     measure=lambda b: float(sum(b)),
                                     cache_file=cache)
     assert best in autotune.candidate_conv_blocks("pallas_conv_log", 16,

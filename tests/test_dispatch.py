@@ -67,9 +67,19 @@ def test_surrogate_routes_to_fused_kernel_on_tpu_only():
 def test_hardware_mode_prefers_arithmetic_kernel_for_log_families():
     assert select_kernel("mitchell", "hardware", 8).name == "pallas_log"
     assert select_kernel("log_our", "hardware", 8).name == "pallas_log"
-    assert select_kernel("appro42", "hardware", 8).name == "pallas_lut_gather"
+    assert select_kernel("appro42", "hardware", 8,
+                         backend="cpu").name == "pallas_lut_gather"
     # without a spec, predicate-gated entries (nibble) are not eligible
-    assert select_kernel("exact", "hardware", 8).name == "pallas_lut_gather"
+    assert select_kernel("exact", "hardware", 8,
+                         backend="cpu").name == "pallas_lut_gather"
+    # the TPU compiler refuses the gather kernels: on a TPU the LUT
+    # families' hardware requests raise at routing, with the inventory
+    assert select_kernel("mitchell", "hardware", 8,
+                         backend="tpu").name == "pallas_log"
+    for family in ("exact", "appro42"):
+        spec = MultiplierSpec(family, 8, True)
+        with pytest.raises(approx_gemm.RoutingError, match="registered"):
+            select_kernel(family, "hardware", 8, backend="tpu", spec=spec)
 
 
 def test_nibble_routing_requires_decomposable_spec():
@@ -367,21 +377,21 @@ def test_autotune_sweep_persists_and_caches(tmp_path):
     def fake_measure(block):
         calls.append(block)
         bm, bk, bn = block
-        return abs(bm - 32) + abs(bk - 64) + abs(bn - 128) + 1.0
+        return abs(bm - 128) + abs(bk - 256) + abs(bn - 128) + 1.0
 
     autotune.clear_memory_cache()
-    best = autotune.best_block("pallas_lut_gather", 8, 512, 512, 512,
+    best = autotune.best_block("pallas_fused_surrogate", 8, 512, 512, 512,
                                backend="tpu", measure=fake_measure,
                                cache_file=cache)
-    assert best == (32, 64, 128)
+    assert best == (128, 256, 128)
     assert len(calls) == len(
-        autotune.candidate_blocks("pallas_lut_gather", 512, 512, 512))
+        autotune.candidate_blocks("pallas_fused_surrogate", 512, 512, 512))
     assert os.path.exists(cache)
 
     # second resolve: served from disk, measure never invoked
     autotune.clear_memory_cache()
     calls.clear()
-    again = autotune.best_block("pallas_lut_gather", 8, 512, 512, 512,
+    again = autotune.best_block("pallas_fused_surrogate", 8, 512, 512, 512,
                                 backend="tpu", measure=fake_measure,
                                 cache_file=cache)
     assert again == best and not calls
@@ -398,15 +408,62 @@ def test_autotune_off_tpu_returns_clipped_heuristic(tmp_path):
     assert not os.path.exists(os.path.join(tmp_path, "t.json"))
 
 
-def test_autotune_rejecting_measure_falls_back(tmp_path):
+def test_autotune_rejecting_measure_falls_back(tmp_path, caplog):
+    """A refused candidate is logged and the sweep goes on without it;
+    when the compiler refuses every candidate the resolve raises instead
+    of handing out a block that was never shown to build."""
+    tried = []
+
+    def oom_unless_small(block):
+        tried.append(block)
+        if block != (8, 64, 64):
+            raise RuntimeError("RESOURCE_EXHAUSTED: VMEM")
+        return 1.0
+
+    autotune.clear_memory_cache()
+    with caplog.at_level("WARNING", logger="repro.core.autotune"):
+        blk = autotune.best_block(
+            "pallas_log", 8, 64, 64, 64, backend="tpu",
+            measure=oom_unless_small,
+            cache_file=os.path.join(tmp_path, "t.json"))
+    assert blk == (8, 64, 64) and len(tried) > 1
+    assert "RESOURCE_EXHAUSTED" in caplog.text
+
     def oom(block):
         raise RuntimeError("RESOURCE_EXHAUSTED: VMEM")
 
-    autotune.clear_memory_cache()
-    blk = autotune.best_block("pallas_log", 8, 64, 64, 64, backend="tpu",
-                              measure=oom,
-                              cache_file=os.path.join(tmp_path, "t.json"))
-    assert blk == autotune.heuristic_block("pallas_log", 64, 64, 64)
+    autotune.clear_memory_cache()          # (and a cold disk cache)
+    with pytest.raises(RuntimeError, match="every candidate block"):
+        autotune.best_block("pallas_log", 8, 64, 64, 64, backend="tpu",
+                            measure=oom,
+                            cache_file=os.path.join(tmp_path, "t2.json"))
+
+
+@pytest.mark.parametrize("kernel", ["pallas_log", "pallas_fused_surrogate"])
+def test_autotune_tpu_measure_runs_concretely_inside_a_trace(monkeypatch,
+                                                             kernel):
+    """Plans resolve while the jitted prefill/decode is traced (inside
+    the layer scan); the TPU sweep's measure must still compile and run
+    each candidate on concrete arrays instead of staging it into the
+    enclosing trace.  The CPU cannot run the kernels compiled, so the
+    test swaps in their interpret-mode form."""
+    from repro.kernels import cim_gemm, ops
+
+    for mod, name in ((ops, "log_matmul_fused"),
+                      (cim_gemm, "cim_gemm_fused")):
+        real = getattr(mod, name)
+        monkeypatch.setattr(mod, name, lambda *a, _f=real, **kw: _f(
+            *a, **dict(kw, interpret=True)))
+    measure = autotune._default_measure(kernel, 8, 8, 128, 128)
+    times = []
+
+    def step(c, _):
+        times.append(measure((8, 128, 128)))
+        return c + 1.0, None
+
+    out = jax.jit(lambda c: jax.lax.scan(step, c, None, length=1)[0])(0.0)
+    assert float(out) == 1.0
+    assert len(times) == 1 and isinstance(times[0], float) and times[0] > 0
 
 
 @pytest.mark.parametrize("garbage", [
@@ -437,13 +494,13 @@ def test_autotune_env_override_respected(tmp_path, monkeypatch):
     monkeypatch.setenv("OPENACM_AUTOTUNE_CACHE", cache)
     assert autotune.cache_path() == cache
     autotune.clear_memory_cache()
-    autotune.best_block("pallas_lut_nibble", 8, 64, 64, 64, backend="tpu",
+    autotune.best_block("pallas_log", 8, 64, 64, 64, backend="tpu",
                         measure=lambda b: float(sum(b)))
     assert os.path.exists(cache)
     # and the override is where a second resolve reads from
     autotune.clear_memory_cache()
     calls = []
-    autotune.best_block("pallas_lut_nibble", 8, 64, 64, 64, backend="tpu",
+    autotune.best_block("pallas_log", 8, 64, 64, 64, backend="tpu",
                         measure=lambda b: calls.append(b) or 1.0)
     assert not calls, "disk row under OPENACM_AUTOTUNE_CACHE was ignored"
 

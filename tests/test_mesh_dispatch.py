@@ -20,10 +20,11 @@ from _hostmesh import run_host_mesh
 _TP_GEMM = """
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core import approx_gemm as ag
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (16, 64), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 32), jnp.float32)
@@ -76,10 +77,11 @@ def test_tp_gemm_bit_identical_to_single_device():
 _TP_CONV = """
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core import approx_gemm as ag
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
     x4 = jax.random.normal(key, (4, 8, 8, 16), jnp.float32)
     results = {}
@@ -136,11 +138,12 @@ def test_tp_conv_bit_identical_to_single_device():
 _RETRACE = """
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core import approx_gemm as ag
 
-    mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-    mesh_b = jax.make_mesh((1, 8), ("data", "model"))
+    mesh_a = make_mesh((2, 4), ("data", "model"))
+    mesh_b = make_mesh((1, 8), ("data", "model"))
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 64), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (64, 32), jnp.float32)
     tiers = [ag.GemmParams(family="exact", bits=8, mode="hardware"),
@@ -235,6 +238,7 @@ def test_mesh_conv_rejects_unsafe_geometry():
 _SERVE_DP = """
     import json
     import jax
+    from repro.launch.mesh import make_mesh
     import numpy as np
     from repro.configs import get_config
     from repro.core.compiler import CiMConfig
@@ -243,7 +247,7 @@ _SERVE_DP = """
     from repro.serving.tiers import AccuracyTier
 
     cfg = get_config("qwen3-1.7b", smoke=True)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     # integer-mode ladder: these tiers route through the shard_map
     # dispatch path and must be BITWISE identical (float tiers under TP
     # reassociate the psum and are only allclose — DESIGN.md §11)

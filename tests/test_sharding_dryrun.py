@@ -9,6 +9,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from _hostmesh import run_host_mesh
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import batch_sharding, logical_to_spec
 
 
@@ -76,7 +77,7 @@ def test_non_divisible_dim_replicates_not_errors():
 def test_batch_sharding_non_divisible_dim0():
     """batch_sharding with dim0 not divisible by the batch axes falls
     back to full replication (long_500k's global batch of 1)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     assert batch_sharding(mesh, 2, dim0=8).spec == P("data", None)
     # dim0=3 on a 1-wide data axis still divides; force non-divisible
     # via a fake 4-wide mesh through the spec-only path
@@ -90,12 +91,13 @@ def test_batch_sharding_non_divisible_dim0():
 _SUBPROC = """
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config, input_specs
     from repro.models.config import ShapeConfig
     from repro.models.transformer import LM
     from repro.parallel.sharding import (param_shardings, batch_sharding,
                                          replicated)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config({arch!r}, smoke=True)
     model = LM(cfg)
     pshape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -104,7 +106,7 @@ _SUBPROC = """
         return model.loss_fn(p, {{"tokens": t}}, k)[0]
     tok = jax.ShapeDtypeStruct((8, 64), jnp.int32)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(jax.grad(loss),
                            in_shardings=(pshard, batch_sharding(mesh, 2),
                                          replicated(mesh)),
@@ -153,6 +155,7 @@ def test_hlo_analysis_counts_loop_bodies():
 _ELASTIC = """
     import json
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config
     from repro.data.pipeline import TokenStream
     from repro.models.transformer import LM
@@ -170,10 +173,10 @@ _ELASTIC = """
                                            ckpt_dir={ckpt!r}), data)
 
     # train on 4x2, checkpoint
-    t1 = build(jax.make_mesh((4, 2), ("data", "model")))
+    t1 = build(make_mesh((4, 2), ("data", "model")))
     out1 = t1.run()
     # "lose" half the fleet: resume on 2x2 with resharded restore
-    t2 = build(jax.make_mesh((2, 2), ("data", "model")))
+    t2 = build(make_mesh((2, 2), ("data", "model")))
     params, opt = t2.init_state()
     step, params, opt = t2.try_resume(params, opt)
     l = jax.tree_util.tree_leaves(params)[0]

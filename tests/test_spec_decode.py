@@ -358,7 +358,7 @@ def test_rollback_and_insert_on_host_mesh():
         new_fill = jnp.asarray(np.maximum(host.slot_pos - 1, 0),
                                jnp.int32)
         rb_h = _rollback(host.caches, new_fill, 3)
-        with mesh:
+        with jax.set_mesh(mesh):
             rb_s = _rollback(shrd.caches, new_fill, 3)
         rollback_eq = all(
             np.array_equal(np.asarray(a), np.asarray(b))
