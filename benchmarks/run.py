@@ -2,10 +2,9 @@
 the per-kernel harnesses (bench_kernels -> BENCH_kernels.json +
 BENCH_dispatch.json; bench_conv -> BENCH_conv.json; bench_attn ->
 BENCH_attn.json; bench_serve -> BENCH_serve.json; bench_faults ->
-BENCH_faults.json; bench_obs -> BENCH_obs.json; bench_dse ->
-BENCH_dse.json; bench_shard -> BENCH_shard.json).  Prints
-``name,us_per_call,derived`` CSV at the end, and exits non-zero when any
-phase failed.
+BENCH_faults.json; bench_dse -> BENCH_dse.json; bench_shard ->
+BENCH_shard.json).  Prints ``name,us_per_call,derived`` CSV at the end,
+and exits non-zero when any phase failed.
 
 Flags:
   --fast      skip the slow CNN table; smaller kernel shape sweep
@@ -22,10 +21,9 @@ import traceback
 
 def main() -> None:
     from benchmarks import (bench_attn, bench_conv, bench_dse,
-                            bench_faults, bench_kernels, bench_obs,
-                            bench_serve, bench_shard, roofline,
-                            table2_ppa, table3_psnr, table4_cnn,
-                            table5_yield)
+                            bench_faults, bench_kernels, bench_serve,
+                            bench_shard, roofline, table2_ppa,
+                            table3_psnr, table4_cnn, table5_yield)
 
     fast = "--fast" in sys.argv
     smoke = "--smoke" in sys.argv
@@ -49,7 +47,6 @@ def main() -> None:
             ("bench_attn", bench_attn.run, path(bench_attn)),
             ("bench_serve", bench_serve.run, None),
             ("bench_faults", bench_faults.run, None),
-            ("bench_obs", bench_obs.run, None),
             ("bench_dse", bench_dse.run, None),
             ("bench_shard", bench_shard.run, path(bench_shard))):
         phases.append((name, functools.partial(fn, **kw), out))

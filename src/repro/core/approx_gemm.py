@@ -1672,7 +1672,9 @@ def _obs_dispatch(op: str, gp: "GemmParams", macs: float,
 
 
 def _plan_kernel(plan) -> str:
-    """Registry name of the kernel a (mesh) plan routes to."""
+    """Registry name of the kernel a (mesh) plan routes to.  Every
+    frontend runs the kernel under `jax.named_scope(<this name>)`, so
+    the device ops it lowers to carry the name in their metadata."""
     return (plan.plan if isinstance(plan, MeshPlan) else plan).entry.name
 
 
@@ -2327,7 +2329,8 @@ def cim_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
             run, stochastic, kernel = hit
             if _OBS_SINK[0] is not None:
                 _obs_dispatch("gemm", gp, float(m) * k * n, True, kernel)
-            return run(x, w, key) if stochastic else run(x, w)
+            with jax.named_scope(kernel):
+                return run(x, w, key) if stochastic else run(x, w)
     if gp.mode not in MODES:
         raise ValueError(f"mode {gp.mode!r} not in {MODES}")
     plan = plan_gemm(gp.family, gp.mode, gp.bits, m, k, n,
@@ -2339,26 +2342,28 @@ def cim_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
     if cached:
         run = _executable_for("cim", gp, plan, stochastic, noise_kind,
                               True, x, w, m, k, n)
+        kernel = _plan_kernel(plan)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, stochastic, _plan_kernel(plan))
+            _FAST_CACHE[fkey] = (run, stochastic, kernel)
         if _OBS_SINK[0] is not None:
-            _obs_dispatch("gemm", gp, float(m) * k * n, False,
-                          _plan_kernel(plan))
-        return run(x, w, key) if stochastic else run(x, w)
+            _obs_dispatch("gemm", gp, float(m) * k * n, False, kernel)
+        with jax.named_scope(kernel):
+            return run(x, w, key) if stochastic else run(x, w)
 
     xf2 = x.reshape((-1, k))
-    if isinstance(plan, MeshPlan):
-        forward = _mesh_forward(gp, plan, preserve_dtype=False)
-        out = _ste_matmul(forward)(xf2, w)
-        return out.reshape(lead + (n,))
-    forward, takes_eps = _cim_forward(gp, plan, noise_kind, stochastic,
-                                      fused=False)
-    if takes_eps:
-        eps = surrogate_noise(key, (xf2.shape[0], n), jnp.float32,
-                              noise_kind)
-        out = _ste_matmul_eps(forward)(xf2, w, eps)
-    else:
-        out = _ste_matmul(forward)(xf2, w)
+    with jax.named_scope(_plan_kernel(plan)):
+        if isinstance(plan, MeshPlan):
+            forward = _mesh_forward(gp, plan, preserve_dtype=False)
+            out = _ste_matmul(forward)(xf2, w)
+            return out.reshape(lead + (n,))
+        forward, takes_eps = _cim_forward(gp, plan, noise_kind, stochastic,
+                                          fused=False)
+        if takes_eps:
+            eps = surrogate_noise(key, (xf2.shape[0], n), jnp.float32,
+                                  noise_kind)
+            out = _ste_matmul_eps(forward)(xf2, w, eps)
+        else:
+            out = _ste_matmul(forward)(xf2, w)
     return out.reshape(lead + (n,))
 
 
@@ -2445,7 +2450,8 @@ def cim_conv2d(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
             run, stochastic, kernel = hit
             if _OBS_SINK[0] is not None:
                 _obs_dispatch("conv", gp, macs, True, kernel)
-            return run(x, w, key) if stochastic else run(x, w)
+            with jax.named_scope(kernel):
+                return run(x, w, key) if stochastic else run(x, w)
     if gp.mode not in MODES:
         raise ValueError(f"mode {gp.mode!r} not in {MODES}")
     if gp.fault is not None:
@@ -2464,22 +2470,25 @@ def cim_conv2d(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
     if cached:
         run = _conv_executable_for(gp, plan, stochastic, noise_kind, x, w,
                                    b, h, w_, c, n)
+        kernel = _plan_kernel(plan)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, stochastic, _plan_kernel(plan))
+            _FAST_CACHE[fkey] = (run, stochastic, kernel)
         if _OBS_SINK[0] is not None:
-            _obs_dispatch("conv", gp, macs, False, _plan_kernel(plan))
-        return run(x, w, key) if stochastic else run(x, w)
+            _obs_dispatch("conv", gp, macs, False, kernel)
+        with jax.named_scope(kernel):
+            return run(x, w, key) if stochastic else run(x, w)
 
-    if isinstance(plan, MeshPlan):
-        return _ste_conv(_mesh_conv_forward(gp, plan), conv)(x, w)
-    forward, takes_eps = _conv_forward(gp, plan, noise_kind, stochastic,
-                                       (b, h, w_, c, n))
-    if takes_eps:
-        oh, ow = conv_out_hw(h, w_, conv.kh, conv.kw, conv.stride)
-        eps = surrogate_noise(key, (b * oh * ow, n), jnp.float32,
-                              noise_kind)
-        return _ste_conv_eps(forward, conv)(x, w, eps)
-    return _ste_conv(forward, conv)(x, w)
+    with jax.named_scope(_plan_kernel(plan)):
+        if isinstance(plan, MeshPlan):
+            return _ste_conv(_mesh_conv_forward(gp, plan), conv)(x, w)
+        forward, takes_eps = _conv_forward(gp, plan, noise_kind,
+                                           stochastic, (b, h, w_, c, n))
+        if takes_eps:
+            oh, ow = conv_out_hw(h, w_, conv.kh, conv.kw, conv.stride)
+            eps = surrogate_noise(key, (b * oh * ow, n), jnp.float32,
+                                  noise_kind)
+            return _ste_conv_eps(forward, conv)(x, w, eps)
+        return _ste_conv(forward, conv)(x, w)
 
 
 # ---------------------------------------------------------------------------
@@ -2558,7 +2567,8 @@ def cim_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             run, _, kernel = hit
             if _OBS_SINK[0] is not None:
                 _obs_dispatch("attn", gp, macs, True, kernel)
-            return run(q, k, v, q_positions, kv_positions, kv_valid)
+            with jax.named_scope(kernel):
+                return run(q, k, v, q_positions, kv_positions, kv_valid)
     plan = plan_attn(gp.family, gp.mode, gp.bits, b, heads, kv_heads, sq,
                      skv, hd, ap, interpret=interpret, block=block,
                      spec=gp.spec)
@@ -2569,9 +2579,10 @@ def cim_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             _FAST_CACHE[fkey] = (run, False, plan.entry.name)
         if _OBS_SINK[0] is not None:
             _obs_dispatch("attn", gp, macs, False, plan.entry.name)
+    else:
+        run = _build_attn_executable(gp, plan)
+    with jax.named_scope(plan.entry.name):
         return run(q, k, v, q_positions, kv_positions, kv_valid)
-    return _build_attn_executable(gp, plan)(q, k, v, q_positions,
-                                            kv_positions, kv_valid)
 
 
 # ---------------------------------------------------------------------------
@@ -2633,7 +2644,8 @@ def model_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
             if _OBS_SINK[0] is not None:
                 _obs_dispatch("model_gemm", gp, float(m) * k * n, True,
                               kernel)
-            return run(x, w, key) if stochastic else run(x, w)
+            with jax.named_scope(kernel):
+                return run(x, w, key) if stochastic else run(x, w)
     mode = gp.mode if apply else "exact"
     plan = plan_gemm(gp.family, mode, gp.bits, m, k, n,
                      spec=gp.routing_spec, mesh=mesh, x_spec=x_spec,
@@ -2643,28 +2655,31 @@ def model_matmul(x: jnp.ndarray, w: jnp.ndarray, gp: GemmParams,
     if cached:
         run = _executable_for("model", gp, plan, stochastic, noise_kind,
                               apply, x, w, m, k, n)
+        kernel = _plan_kernel(plan)
         with _EXEC_LOCK:
-            _FAST_CACHE[fkey] = (run, stochastic, _plan_kernel(plan))
+            _FAST_CACHE[fkey] = (run, stochastic, kernel)
         if _OBS_SINK[0] is not None:
             _obs_dispatch("model_gemm", gp, float(m) * k * n, False,
-                          _plan_kernel(plan))
-        return run(x, w, key) if stochastic else run(x, w)
+                          kernel)
+        with jax.named_scope(kernel):
+            return run(x, w, key) if stochastic else run(x, w)
 
-    if isinstance(plan, MeshPlan):
-        forward = _mesh_forward(gp, plan, preserve_dtype=True)
+    with jax.named_scope(_plan_kernel(plan)):
+        if isinstance(plan, MeshPlan):
+            forward = _mesh_forward(gp, plan, preserve_dtype=True)
+            x2 = x.reshape((-1, k))
+            return _ste_matmul(forward)(x2, w).reshape(lead + (n,))
+        kind, f, flag = _model_forward(gp, plan, noise_kind, stochastic,
+                                       apply, fused=False)
+        if kind == "plain":
+            return f(x, w, key)
+        # STE kernel-backed paths must see a rank-2 x: the custom_vjp
+        # backward does xf.T @ g, so flatten leading dims OUTSIDE the vjp
         x2 = x.reshape((-1, k))
-        return _ste_matmul(forward)(x2, w).reshape(lead + (n,))
-    kind, f, flag = _model_forward(gp, plan, noise_kind, stochastic, apply,
-                                   fused=False)
-    if kind == "plain":
-        return f(x, w, key)
-    # STE kernel-backed paths must see a rank-2 x: the custom_vjp
-    # backward does xf.T @ g, so flatten leading dims OUTSIDE the vjp
-    x2 = x.reshape((-1, k))
-    if flag:
-        eps = surrogate_noise(key, (x2.shape[0], n), jnp.float32,
-                              noise_kind)
-        out = _ste_matmul_eps(f)(x2, w, eps)
-    else:
-        out = _ste_matmul(f)(x2, w)
+        if flag:
+            eps = surrogate_noise(key, (x2.shape[0], n), jnp.float32,
+                                  noise_kind)
+            out = _ste_matmul_eps(f)(x2, w, eps)
+        else:
+            out = _ste_matmul(f)(x2, w)
     return out.reshape(lead + (n,))

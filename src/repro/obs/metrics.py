@@ -1,8 +1,7 @@
 """Telemetry core: counters, gauges, histograms, span/event rings
 (DESIGN.md §15).
 
-Design contract (the overhead budget `benchmarks/bench_obs.py` pins at
-<= 3% serving tokens/s):
+Design contract:
 
   * **Host-side only.**  Nothing here is ever called from inside a
     jitted computation — instruments record at dispatch boundaries
@@ -137,6 +136,8 @@ class Span:
     dur: float
     tid: int = 0                      # trace row: request id / lane row
     labels: Dict[str, object] = dataclasses.field(default_factory=dict)
+    sid: Optional[int] = None         # program spans: id and the id of
+    parent: Optional[int] = None      # the span open around it
 
 
 class Ring:

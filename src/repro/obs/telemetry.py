@@ -10,24 +10,34 @@ One object wires the whole telemetry spine together:
     before the retrace probe arms) and attributes estimated Joules to
     lanes *and* live requests per scheduler event;
   * records per-request lifecycle spans (queue-wait -> prefill ->
-    decode, plus retry spans on sentinel trips) and per-lane engine
-    spans (decode/spec rounds) into the registry's span ring —
-    `obs/export.chrome_trace` renders them for Perfetto;
+    decode, plus retry spans on sentinel trips) into the registry's
+    span ring, and times the program's own phases with `span()`: the
+    scheduler tick (`step`), each grouped prefill (`admit` ->
+    `prefill.dispatch` / `prefill.fetch` / `prefill.sample`) and each
+    pool decode (`decode_round` -> `decode.*`, or `spec_round`).  Each
+    such span is also a `jax.profiler.TraceAnnotation`, so it sits in a
+    profiler trace on the device trace's clock; `obs/export.
+    chrome_trace` renders the ring for Perfetto;
   * folds sentinel scores, breaker transitions, and structured
     `TripEvent`s into gauges/counters and the event ring.
 
-Every hook is a host-side dict update gated on
-``registry.enabled`` — the overhead contract `benchmarks/bench_obs.py`
-enforces (<= 3% serving tokens/s, zero steady-state retraces).
+Every hook is a host-side dict update gated on ``registry.enabled``.
+An engine without telemetry pays one `is None` branch per span site.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Dict, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from .energy import LaneEnergyMeter
-from .metrics import MetricsRegistry
+from .metrics import MetricsRegistry, Span
+
+# what a span site enters when it has no telemetry (stateless, shared)
+NOSPAN = contextlib.nullcontext()
 
 # span-duration histogram buckets (seconds): microseconds to minutes
 _TIME_BUCKETS = (1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1, 0.3, 1.0,
@@ -51,13 +61,14 @@ class EngineTelemetry:
         r = self.registry
         self.dispatch_calls = r.counter(
             "repro_dispatch_calls_total",
-            "dispatch-frontend invocations (eager calls + jit traces)")
+            "traced dispatches: eager frontend calls and jit traces "
+            "(a compiled step's replays are not counted)")
         self.kernel_calls = r.counter(
             "repro_dispatch_kernel_calls_total",
-            "dispatch-frontend invocations by routed registry kernel")
+            "traced dispatches by routed registry kernel")
         self.dispatch_macs = r.counter(
             "repro_dispatch_macs_total",
-            "MACs announced at dispatch boundaries")
+            "MACs of the traced dispatches (once per trace, not per run)")
         self.retraces = r.counter(
             "repro_dispatch_retraces_total",
             "executable traces (trace_count probe)")
@@ -119,6 +130,9 @@ class EngineTelemetry:
         self.meters: Dict[str, LaneEnergyMeter] = {}
         self.request_energy_j: Dict[int, float] = {}
         self._tids: Dict[str, int] = {}
+        self._open: List["_OpenSpan"] = []       # program spans, nested
+        self._next_sid = 0
+        self.now: Callable[[], float] = lambda: 0.0   # the engine's clock
         self._attached = False
         if attach:
             self.attach()
@@ -165,19 +179,40 @@ class EngineTelemetry:
     def alloc_search(self, event: str, count: int) -> None:
         self.alloc_search_c.inc(count, event=event)
 
-    # -- engine lifecycle ---------------------------------------------------
-    def _tid(self, lane: str) -> int:
-        """Stable negative trace row per lane (request rows are >= 0)."""
-        tid = self._tids.get(lane)
+    # -- program spans ------------------------------------------------------
+    def span(self, name: str, lane: Optional[str] = None, **labels):
+        """Time one phase of the program: a context manager that opens a
+        profiler `TraceAnnotation(name)` and, on exit, appends the span
+        to the ring on the engine clock (`self.now`), with its parent
+        (the span open around it when it started).  `lane` puts it on
+        that lane's trace row; otherwise it shares its parent's row (the
+        scheduler's row at the top).  The `_OpenSpan` it returns takes
+        labels until it closes; a disabled registry keeps none."""
+        if lane is not None:
+            labels["lane"] = lane
+            tid = self._tid(lane)
+        else:
+            tid = (self._open[-1].tid if self._open
+                   else self._row("scheduler"))
+        return _OpenSpan(self, name, tid, labels)
+
+    def _row(self, key: str) -> int:
+        """Stable negative trace row per lane or the scheduler (request
+        rows are >= 0)."""
+        tid = self._tids.get(key)
         if tid is None:
             tid = -(len(self._tids) + 1)
-            self._tids[lane] = tid
+            self._tids[key] = tid
         return tid
+
+    def _tid(self, lane: str) -> int:
+        return self._row(f"lane {lane}")
 
     @property
     def tid_names(self) -> Dict[int, str]:
-        return {tid: f"lane {name}" for name, tid in self._tids.items()}
+        return {tid: key for key, tid in self._tids.items()}
 
+    # -- engine lifecycle ---------------------------------------------------
     def on_warmup(self, engine) -> None:
         """Build the per-lane energy meters (eval_shape MAC profiling;
         cheap, abstract).  MUST run before the engine arms its
@@ -203,7 +238,7 @@ class EngineTelemetry:
                 self.request_energy_j.get(rid, 0.0) + share
 
     def on_prefill(self, lane: str, n_prompts: int, prompt_len: int,
-                   rids: Sequence[int], now: float) -> None:
+                   rids: Sequence[int]) -> None:
         if not self.registry.enabled:
             return
         self.prefills_c.inc(1, tier=lane)
@@ -213,31 +248,27 @@ class EngineTelemetry:
             self._update_energy(lane, m)
 
     def on_decode_round(self, lane: str, rids: Sequence[int],
-                        t0: float, dur: float) -> None:
+                        dur: float) -> None:
+        """After a `decode_round` span of `dur` seconds closed."""
         if not self.registry.enabled:
             return
         self.decode_rounds_c.inc(1, tier=lane)
         self.decode_h.observe(dur, tier=lane)
-        self.registry.span("decode_round", t0, dur, tid=self._tid(lane),
-                           lane=lane, n_live=len(rids))
         m = self.meters.get(lane)
         if m is not None:
             self._share(m.on_decode(), rids)
             self._update_energy(lane, m)
 
     def on_spec_round(self, lane: str, k: int, d_rounds: int,
-                      d_drafted: int, d_accepted: int, d_emitted: int,
-                      rids: Sequence[int], t0: float,
-                      dur: float) -> None:
+                      d_drafted: int, d_accepted: int,
+                      rids: Sequence[int], dur: float) -> None:
+        """After a `spec_round` span of `dur` seconds closed."""
         if not self.registry.enabled:
             return
         self.decode_h.observe(dur, tier=lane)
         self.spec_rounds_c.inc(d_rounds, tier=lane, k=k)
         self.spec_drafted_c.inc(d_drafted, tier=lane, k=k)
         self.spec_accepted_c.inc(d_accepted, tier=lane, k=k)
-        self.registry.span("spec_round", t0, dur, tid=self._tid(lane),
-                           lane=lane, k=k, rounds=d_rounds,
-                           emitted=d_emitted)
         m = self.meters.get(lane)
         if m is not None:
             self._share(m.on_spec_rounds(k, d_rounds), rids)
@@ -313,3 +344,35 @@ class EngineTelemetry:
     def _update_energy(self, lane: str, m: LaneEnergyMeter) -> None:
         self.energy_g.set(m.energy_j, tier=lane)
         self.ept_g.set(m.energy_per_token_j, tier=lane)
+
+
+class _OpenSpan:
+    """One program span while it is open (see `EngineTelemetry.span`)."""
+
+    __slots__ = ("tel", "name", "tid", "labels", "sid", "parent", "t0",
+                 "dur", "_ann")
+
+    def __init__(self, tel: EngineTelemetry, name: str, tid: int,
+                 labels: Dict[str, object]):
+        self.tel, self.name, self.tid, self.labels = tel, name, tid, labels
+
+    def __enter__(self) -> "_OpenSpan":
+        tel = self.tel
+        self.sid = tel._next_sid
+        tel._next_sid += 1
+        self.parent = tel._open[-1].sid if tel._open else None
+        tel._open.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = tel.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tel = self.tel
+        self.dur = tel.now() - self.t0
+        self._ann.__exit__(*exc)
+        tel._open.pop()
+        if tel.registry.enabled:
+            tel.registry.spans.append(Span(self.name, self.t0, self.dur,
+                                           self.tid, self.labels, self.sid,
+                                           self.parent))

@@ -36,10 +36,13 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import weakref
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.obs.telemetry import NOSPAN
 
 from .sentinel import LaneHealthError
 
@@ -174,6 +177,14 @@ class TripEvent:
         return [f.name for f in dataclasses.fields(self)]
 
 
+def _admit_rows(backend, n: int, prompt_bucket: int) -> int:
+    """Token rows one grouped prefill computes: group bucket x prompt
+    bucket (a backend without group buckets computes its `n` rows)."""
+    groups = getattr(backend, "group_buckets", None)
+    g = _bucket_up(n, groups, "admission group") if groups else n
+    return g * prompt_bucket
+
+
 def _bucket_up(v: int, buckets: Sequence[int], what: str) -> int:
     for b in buckets:
         if b >= v:
@@ -275,6 +286,7 @@ class LMLaneBackend:
         self.slot_pos = np.zeros(self.n_slots, np.int64)
         self.last_prefill_logits: Optional[np.ndarray] = None
         self.last_decode_logits: Optional[np.ndarray] = None
+        self.telemetry = None        # obs.EngineTelemetry, set by the engine
 
         # max_len must be a trace-time constant (it sizes the group
         # caches), so it is closed over — same trick as launch/serve.py
@@ -331,11 +343,25 @@ class LMLaneBackend:
         return max(self.group_buckets)
 
     # -- execution ---------------------------------------------------------
+    @staticmethod
+    def _fetch(logits) -> np.ndarray:
+        """The last position's logits on the host, (B, 1, V) float32:
+        waits for the step that makes them, then copies.  The slice is
+        its own tiny XLA executable, compiled by `warmup`."""
+        return np.asarray(logits[:, -1:, :], np.float32)
+
+    def _sample(self, logits, fetch: str,
+                sample: str) -> Tuple[np.ndarray, np.ndarray]:
+        """`_fetch` then `_greedy`, each under its own span."""
+        tel = self.telemetry
+        with (tel.span(fetch) if tel is not None else NOSPAN):
+            lg = self._fetch(logits)
+        with (tel.span(sample) if tel is not None else NOSPAN):
+            return self._greedy(lg)
+
     def _greedy(self, logits) -> Tuple[np.ndarray, np.ndarray]:
-        """Host-side greedy sampling.  The slice+cast is its own tiny
-        XLA executable (it runs outside the jitted step), so it MUST be
-        part of warmup — a per-shape compile here would otherwise land
-        on the first real request.
+        """Host-side greedy sampling over (B, S, V) logits (the last
+        position's).
 
         Non-finite logits raise a diagnostic `LaneHealthError` instead
         of silently emitting argmax-of-garbage (np.argmax would return
@@ -360,16 +386,18 @@ class LMLaneBackend:
         toks = np.zeros((g_bkt, p_bkt), np.int32)
         lens = np.ones(g_bkt, np.int32)       # padding rows: 1-token stubs
         slot_idx = np.full(g_bkt, self.n_slots, np.int32)   # OOB sentinel
-        for i, (pr, sl) in enumerate(zip(prompts, slots)):
-            toks[i, :len(pr)] = pr
-            lens[i] = len(pr)
-            slot_idx[i] = sl
-        with self._ctx():
-            logits, grp = self._prefill(self.params, jnp.asarray(toks),
-                                        jnp.asarray(lens))
-            self.caches = self._insert(self.caches, grp,
-                                       jnp.asarray(slot_idx))
-        first, lg = self._greedy(logits)
+        tel = self.telemetry
+        with (tel.span("prefill.dispatch") if tel is not None else NOSPAN):
+            for i, (pr, sl) in enumerate(zip(prompts, slots)):
+                toks[i, :len(pr)] = pr
+                lens[i] = len(pr)
+                slot_idx[i] = sl
+            with self._ctx():
+                logits, grp = self._prefill(self.params, jnp.asarray(toks),
+                                            jnp.asarray(lens))
+                self.caches = self._insert(self.caches, grp,
+                                           jnp.asarray(slot_idx))
+        first, lg = self._sample(logits, "prefill.fetch", "prefill.sample")
         self.last_prefill_logits = lg[:g]
         for i, sl in enumerate(slots):
             self.slot_tokens[sl] = first[i]
@@ -380,17 +408,19 @@ class LMLaneBackend:
         """One greedy decode step for the whole pool (idle slots ride
         along masked by their own fill level; their output is ignored)."""
         jnp = self._jnp
-        tok = jnp.asarray(self.slot_tokens[:, None], jnp.int32)
-        pos = jnp.asarray(self.slot_pos, jnp.int32)
-        if self.mesh is not None:
-            import jax
+        tel = self.telemetry
+        with (tel.span("decode.dispatch") if tel is not None else NOSPAN):
+            tok = jnp.asarray(self.slot_tokens[:, None], jnp.int32)
+            pos = jnp.asarray(self.slot_pos, jnp.int32)
+            if self.mesh is not None:
+                import jax
 
-            tok = jax.device_put(tok, self._tok_shard)
-            pos = jax.device_put(pos, self._pos_shard)
-        with self._ctx():
-            logits, self.caches = self._decode(self.params, self.caches,
-                                               tok, pos)
-        nxt, lg = self._greedy(logits)
+                tok = jax.device_put(tok, self._tok_shard)
+                pos = jax.device_put(pos, self._pos_shard)
+            with self._ctx():
+                logits, self.caches = self._decode(self.params,
+                                                   self.caches, tok, pos)
+        nxt, lg = self._sample(logits, "decode.fetch", "decode.sample")
         self.slot_tokens = nxt.astype(np.int64)
         self.slot_pos += 1
         self.last_decode_logits = lg
@@ -400,7 +430,15 @@ class LMLaneBackend:
         """Compile every steady-state executable: (G, P) prefills +
         inserts, and the pool decode.  The sentinel-slot inserts and the
         zero-position decode leave no live state behind (idle rows are
-        fully overwritten on first real admission)."""
+        fully overwritten on first real admission).  Warm-up is not
+        served work: it records no spans."""
+        tel, self.telemetry = self.telemetry, None
+        try:
+            return self._warmup()
+        finally:
+            self.telemetry = tel
+
+    def _warmup(self) -> int:
         jnp = self._jnp
         n = 0
         with self._ctx():
@@ -411,7 +449,7 @@ class LMLaneBackend:
                     logits, grp = self._prefill(self.params, toks, lens)
                     sent = jnp.full((g_bkt,), self.n_slots, jnp.int32)
                     self.caches = self._insert(self.caches, grp, sent)
-                    self._greedy(logits)   # compiles the sampling slice
+                    self._fetch(logits)    # compiles the sampling slice
                     n += 1
         self.decode_round()                # pool decode (+ sampling slice)
         self.slot_tokens[:] = 0            # zero-position warm decode
@@ -479,6 +517,14 @@ class ServingEngine:
         for name, sen in (sentinels or {}).items():
             self.lanes[name].sentinel = sen
         self.telemetry = telemetry               # obs.EngineTelemetry
+        for lane in self.lanes.values():
+            if hasattr(lane.backend, "telemetry"):
+                lane.backend.telemetry = telemetry
+        if telemetry is not None:
+            # the telemetry reads this engine's clock without keeping
+            # the engine (and its KV pools) alive
+            span_now = weakref.WeakMethod(self._span_now)
+            telemetry.now = lambda: span_now()()
         self.results: Dict[int, RequestResult] = {}
         self.active_tokens = 0
         self.peak_running = 0
@@ -488,6 +534,7 @@ class ServingEngine:
         self._expected: Dict[str, int] = {}
         self._trace_mark: Optional[int] = None
         self._clock = None                       # set by run()
+        self._tick_now = 0.0                     # the current tick's `now`
 
     # -- warmup / retrace probe -------------------------------------------
     def warmup(self) -> int:
@@ -611,6 +658,7 @@ class ServingEngine:
             lane.running[slot] = _Running(req, rr)
         # group by prompt bucket (one traced shape per admit call),
         # chunked to the largest pre-warmed group bucket
+        tel = self.telemetry
         groups: Dict[int, List[Tuple[Request, int]]] = {}
         for req, slot in taken:
             pb = (lane.backend.prompt_bucket(len(req.prompt))
@@ -623,23 +671,31 @@ class ServingEngine:
                 chunk = members[i:i + max_g]
                 prompts = [r.prompt for r, _ in chunk]
                 slots = [s for _, s in chunk]
-                first = lane.backend.admit(prompts, slots)
-                if self.telemetry is not None:
-                    self.telemetry.on_prefill(
-                        lane.name, len(chunk), pb,
-                        [r.rid for r, _ in chunk], now)
+                if tel is None:
+                    first = lane.backend.admit(prompts, slots)
+                else:
+                    with tel.span("admit", lane.name,
+                                  rows=_admit_rows(lane.backend, len(chunk),
+                                                   pb),
+                                  useful=sum(len(p) for p in prompts)):
+                        first = lane.backend.admit(prompts, slots)
+                    tel.on_prefill(lane.name, len(chunk), pb,
+                                   [r.rid for r, _ in chunk])
+                t = self._now_fine(now)
                 pre_lg = getattr(lane.backend, "last_prefill_logits",
                                  None)
                 for j, (req, slot) in enumerate(chunk):
                     lg = (pre_lg[j] if self.record_logits
                           and pre_lg is not None else None)
-                    self._emit(lane, slot, int(first[j]), now, lg)
+                    self._emit(lane, slot, int(first[j]), t, lg)
         self.peak_running = max(self.peak_running,
                                 sum(len(l.running) for l in
                                     self.lanes.values()))
 
     def _emit(self, lane: _Lane, slot: int, tok: int, now: float,
               logits_row=None) -> None:
+        """Hand one token to its request; `now` is when the backend
+        call that made it returned."""
         run = lane.running[slot]
         rr = run.result
         rr.tokens.append(tok)
@@ -662,10 +718,14 @@ class ServingEngine:
                 self.telemetry.on_request_done(rr, lane.name)
 
     def _now_fine(self, now: float) -> float:
-        """Sub-tick timestamp for span durations: the run() clock when
-        one is live, else the tick's own `now` (durations degrade to 0
-        under direct step() driving — deterministic tests)."""
+        """Sub-tick timestamp: the run() clock when one is live, else
+        the tick's own `now` (stamps stay on the tick under direct
+        step() driving — deterministic tests)."""
         return self._clock.now() if self._clock is not None else now
+
+    def _span_now(self) -> float:
+        """The telemetry's clock: `_now_fine` of the current tick."""
+        return self._now_fine(self._tick_now)
 
     def step(self, now: Optional[float] = None) -> List[RequestResult]:
         """One scheduler tick: release due backoff requeues, probe
@@ -678,6 +738,13 @@ class ServingEngine:
         round's output never reaches a result (DESIGN.md §14).
         Returns results completed this tick."""
         now = 0.0 if now is None else now
+        self._tick_now = now
+        tel = self.telemetry
+        with (tel.span("step") if tel is not None else NOSPAN):
+            return self._tick(now)
+
+    def _tick(self, now: float) -> List[RequestResult]:
+        tel = self.telemetry
         done_before = {rid for rid, r in self.results.items() if r.done}
         if self._deferred:
             due = [d for d in self._deferred if d[0] <= now]
@@ -708,19 +775,23 @@ class ServingEngine:
                 # exact reference for the CURRENT state — must precede
                 # the lane's own decode, which donates the caches
                 shadow = sen.shadow(lane.backend)
-            t0 = self._now_fine(now)
             try:
-                nxt = lane.backend.decode_round()
+                if tel is None:
+                    nxt = lane.backend.decode_round()
+                else:
+                    with tel.span("decode_round", lane.name,
+                                  rows=lane.backend.n_slots,
+                                  useful=len(lane.running)) as sp:
+                        nxt = lane.backend.decode_round()
+                    tel.on_decode_round(
+                        lane.name, [r.result.rid for r in
+                                    lane.running.values()], sp.dur)
             except LaneHealthError as e:
                 if sen is None:
                     raise
                 self._trip(lane, now, str(e))
                 continue
-            if self.telemetry is not None:
-                self.telemetry.on_decode_round(
-                    lane.name, [r.result.rid for r in
-                                lane.running.values()],
-                    t0, self._now_fine(now) - t0)
+            t = self._now_fine(now)
             if shadow is not None:
                 tripped = sen.observe(
                     lane.backend.last_decode_logits, shadow,
@@ -737,7 +808,7 @@ class ServingEngine:
             for slot in sorted(lane.running):
                 lg = (dec_lg[slot] if self.record_logits
                       and dec_lg is not None else None)
-                self._emit(lane, slot, int(nxt[slot]), now, lg)
+                self._emit(lane, slot, int(nxt[slot]), t, lg)
         if self.check_invariants:
             self._check()
         return [r for rid, r in self.results.items()
@@ -859,17 +930,24 @@ class ServingEngine:
             if run.req.eos_id is not None:
                 eos[slot] = run.req.eos_id
         tel = self.telemetry
-        pre = ((b.n_rounds, b.n_drafted, b.n_accepted, b.n_emitted)
-               if tel is not None and hasattr(b, "n_rounds") else None)
-        t0 = self._now_fine(now)
-        toks, counts = b.spec_round(remaining, eos)
-        if pre is not None:
-            tel.on_spec_round(
-                lane.name, getattr(b, "draft_k", 0),
-                b.n_rounds - pre[0], b.n_drafted - pre[1],
-                b.n_accepted - pre[2], b.n_emitted - pre[3],
-                [r.result.rid for r in lane.running.values()],
-                t0, self._now_fine(now) - t0)
+        if tel is None:
+            toks, counts = b.spec_round(remaining, eos)
+        else:
+            pre = ((b.n_rounds, b.n_drafted, b.n_accepted, b.n_emitted)
+                   if hasattr(b, "n_rounds") else None)
+            with tel.span("spec_round", lane.name) as sp:
+                toks, counts = b.spec_round(remaining, eos)
+                if pre is not None:
+                    k = getattr(b, "draft_k", 0)
+                    d_rounds = b.n_rounds - pre[0]
+                    sp.labels.update(k=k, rounds=d_rounds,
+                                     emitted=b.n_emitted - pre[3])
+            if pre is not None:
+                tel.on_spec_round(
+                    lane.name, k, d_rounds, b.n_drafted - pre[1],
+                    b.n_accepted - pre[2],
+                    [r.result.rid for r in lane.running.values()], sp.dur)
+        t = self._now_fine(now)
         toks, counts = np.asarray(toks), np.asarray(counts)
         lg = getattr(b, "last_spec_logits", None)
         if counts.ndim == 1:
@@ -881,7 +959,7 @@ class ServingEngine:
                 for i in range(int(counts[slot, r])):
                     row = (lg[slot, r, i] if self.record_logits
                            and lg is not None else None)
-                    self._emit(lane, slot, int(toks[slot, r, i]), now, row)
+                    self._emit(lane, slot, int(toks[slot, r, i]), t, row)
 
     def _check(self) -> None:
         total = 0

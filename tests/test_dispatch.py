@@ -512,3 +512,22 @@ def test_autotune_off_tpu_heuristic_never_writes_disk(tmp_path, monkeypatch):
     for kernel in ("pallas_lut_gather", "pallas_lut_nibble", "pallas_log"):
         autotune.best_block(kernel, 8, 128, 128, 128, backend="cpu")
     assert not os.path.exists(cache)
+
+
+@pytest.mark.parametrize("family,mode,kernel", [
+    ("exact", "exact", "mxu_dot"),
+    ("appro42", "surrogate_fast", "xla_surrogate"),
+    ("mitchell", "hardware", "pallas_log"),
+])
+def test_model_gemm_ops_carry_the_kernel_name(family, mode, kernel):
+    """A model_gemm dispatch runs under `jax.named_scope(<kernel>)`, so
+    the ops it lowers to name the registry kernel in their metadata
+    (what a device trace shows as the op's name stack)."""
+    gp = GemmParams(family=family, bits=8, mode=mode)
+    x = jnp.ones((8, 64), jnp.bfloat16)
+    w = jnp.ones((64, 32), jnp.bfloat16)
+    hit = approx_gemm.model_matmul(x, w, gp)         # fills the front cache
+    f = jax.jit(lambda x, w: approx_gemm.model_matmul(x, w, gp))
+    text = f.lower(x, w).as_text(debug_info=True)
+    assert f'jit(<lambda>)/{kernel}/' in text
+    np.testing.assert_array_equal(np.asarray(f(x, w)), np.asarray(hit))

@@ -1,6 +1,6 @@
 """Telemetry spine (DESIGN.md §15).
 
-Four layers:
+Five layers:
 
   * instrument primitives — counter/gauge label keying, histogram
     bucketing (Prometheus-inclusive upper bounds + implicit +inf),
@@ -16,7 +16,11 @@ Four layers:
     lifecycle spans, `engine.metrics()`, structured `TripEvent`s with
     dict back-compat, and retry spans for work a trip displaces —
     plus `EngineStats.from_results` edge cases and the injectable
-    serving clocks.
+    serving clocks;
+  * program spans on a smoke-width engine — the `step` ⊃ `admit` /
+    `decode_round` ⊃ dispatch/fetch/sample tree with parents, and the
+    off path (no telemetry: no span, annotation or clock read) — and
+    token stamps taken after the backend call (TTFT includes it).
 """
 
 import dataclasses
@@ -320,9 +324,11 @@ def test_telemetry_request_lifecycle_spans():
     # one queue/prefill/decode span per completed request, tid = rid
     for name in ("queue", "prefill", "decode"):
         assert sorted(s.tid for s in by_name[name]) == list(range(6))
-    # lane rows are negative and named
+    # lane rows and the scheduler's row are negative and named
     assert all(s.tid < 0 for s in by_name["decode_round"])
-    assert set(tel.tid_names.values()) == {"lane a", "lane b"}
+    assert all(s.tid < 0 for s in by_name["step"])
+    assert set(tel.tid_names.values()) == {"lane a", "lane b",
+                                           "scheduler"}
 
     tok_total = sum(len(r.tokens) for r in res.values())
     assert tel.tokens_c.total == tok_total
@@ -432,3 +438,181 @@ def test_dispatch_sink_protocol_counts():
                                    bits=8) == 200.0
     assert tel.retraces.total == 1
     assert tel.autotune_c.value(outcome="disk_hit") == 1
+
+
+# ---------------------------------------------------------------------------
+# program spans (the scheduler tick and each model step's phases)
+# ---------------------------------------------------------------------------
+
+
+class _CountingClock(Clock):
+    """A real clock that counts its reads."""
+
+    def __init__(self):
+        self.inner = RealClock()
+        self.reads = 0
+
+    def now(self) -> float:
+        self.reads += 1
+        return self.inner.now()
+
+
+class _CountingAnnotation:
+    opened = 0
+
+    def __init__(self, name):
+        type(self).opened += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+@pytest.fixture(scope="module")
+def smoke_lanes():
+    """One warmed exact-tier lane at smoke width (compiled once)."""
+    from repro.configs import get_config
+    from repro.serving import build_engine, build_tiers
+
+    cfg = get_config("qwen3-1.7b", smoke=True)
+    eng = build_engine(cfg, tiers=build_tiers(families=("exact",)),
+                       slots_per_tier=2, max_len=32, prompt_buckets=(8,),
+                       group_buckets=(1, 2))
+    eng.warmup()
+    return {n: lane.backend for n, lane in eng.lanes.items()}, eng.router
+
+
+def _smoke_reqs(n=3):
+    return [_req(i, tier="exact", plen=5 + i, max_new=3) for i in range(n)]
+
+
+def _drive(eng, clock, reqs) -> int:
+    """Direct step() driving on `clock` until every request is done;
+    returns the number of ticks (one clock read each)."""
+    eng._clock = clock
+    for r in reqs:
+        eng.submit(r)
+    for n in range(1, 50):
+        eng.step(clock.now())
+        if all(eng.results[r.rid].done for r in reqs):
+            return n
+    raise AssertionError("requests did not finish")
+
+
+def test_program_span_tree(smoke_lanes):
+    backends, router = smoke_lanes
+    tel = EngineTelemetry(attach=False, energy=False)
+    eng = ServingEngine(backends, router, telemetry=tel)
+    assert all(b.telemetry is tel for b in backends.values())
+    _drive(eng, RealClock(), _smoke_reqs())
+    spans = [s for s in tel.registry.spans.items() if s.sid is not None]
+    by_sid = {s.sid: s for s in spans}
+    names = {s.name for s in spans}
+    assert names == {"step", "admit", "prefill.dispatch", "prefill.fetch",
+                     "prefill.sample", "decode_round", "decode.dispatch",
+                     "decode.fetch", "decode.sample"}
+    tree = {"admit": "step", "decode_round": "step",
+            "prefill.dispatch": "admit", "prefill.fetch": "admit",
+            "prefill.sample": "admit", "decode.dispatch": "decode_round",
+            "decode.fetch": "decode_round", "decode.sample": "decode_round"}
+    for s in spans:
+        if s.name == "step":
+            assert s.parent is None
+            continue
+        parent = by_sid[s.parent]
+        assert parent.name == tree[s.name]
+        assert s.tid == parent.tid or s.name in ("admit", "decode_round")
+        # a child lies inside its parent, on the same clock
+        assert parent.t0 <= s.t0 and s.t0 + s.dur <= parent.t0 + parent.dur
+    for s in spans:
+        kids = [c for c in spans if c.parent == s.sid]
+        assert sum(c.dur for c in kids) <= s.dur
+    admits = [s for s in spans if s.name == "admit"]
+    # 3 prompts of 5-7 tokens into 2 slots: a group of 2, then 1
+    assert sorted((s.labels["rows"], s.labels["useful"]) for s in admits) \
+        == [(8, 7), (16, 11)]
+    assert all(s.labels["lane"] == "exact" and s.tid < 0 for s in admits)
+    rounds = [s for s in spans if s.name == "decode_round"]
+    assert all(s.labels["rows"] == 2 and 1 <= s.labels["useful"] <= 2
+               for s in rounds)
+    assert tel.decode_rounds_c.total == len(rounds)
+    assert all(s.dur > 0 for s in spans if s.name == "step")
+
+
+def test_no_telemetry_no_span_no_annotation_no_clock_read(smoke_lanes,
+                                                          monkeypatch):
+    from repro.obs import telemetry as telmod
+
+    monkeypatch.setattr(telmod, "TraceAnnotation", _CountingAnnotation)
+    backends, router = smoke_lanes
+    calls = {"admit": 0, "decode_round": 0}
+    for b in backends.values():
+        for name in calls:
+            def counted(*a, _f=getattr(b, name), _n=name):
+                calls[_n] += 1
+                return _f(*a)
+            monkeypatch.setattr(b, name, counted)
+
+    def served(tel):
+        _CountingAnnotation.opened = 0
+        calls.update(admit=0, decode_round=0)
+        clock = _CountingClock()
+        eng = ServingEngine(backends, router, telemetry=tel)
+        ticks = _drive(eng, clock, _smoke_reqs())
+        # _drive reads once per tick; the engine stamps tokens once
+        # after each backend call; every other read is a span's
+        return clock.reads - ticks - sum(calls.values())
+
+    assert served(None) == 0
+    assert all(b.telemetry is None for b in backends.values())
+    assert _CountingAnnotation.opened == 0
+    assert calls["admit"] == 2 and calls["decode_round"] >= 2
+    tel = EngineTelemetry(attach=False, energy=False)
+    span_reads = served(tel)
+    n_spans = sum(1 for s in tel.registry.spans.items()
+                  if s.sid is not None)
+    assert span_reads == 2 * n_spans > 0
+    assert _CountingAnnotation.opened == n_spans
+
+
+def test_ttft_includes_the_prefill():
+    """Tokens are stamped when the backend call that made them returns:
+    a prefill that takes 0.25 s shows whole in TTFT and the `prefill`
+    lifecycle span."""
+    sim = SimClock()
+
+    class SlowPrefill(FakeLane):
+        def admit(self, prompts, slots):
+            sim.t += 0.25
+            return super().admit(prompts, slots)
+
+    tel = EngineTelemetry(attach=False, energy=False)
+    eng = ServingEngine({"a": SlowPrefill(2)}, TierRouter(_fake_tiers(("a",))),
+                        telemetry=tel)
+    res = eng.run([_req(0, max_new=2), _req(1, max_new=2)], clock=sim)
+    for r in res.values():
+        assert r.t_admit == 0.0
+        assert r.t_first == pytest.approx(0.25)
+    assert EngineStats.from_results(res, eng.last_run_s).p50_ttft_ms == \
+        pytest.approx(250.0)
+    prefill = [s for s in tel.registry.spans.items() if s.name == "prefill"]
+    assert [s.dur for s in prefill] == pytest.approx([0.25, 0.25])
+    assert tel.ttft_h.snapshot(tier="a")["sum"] == pytest.approx(0.5)
+    admit = [s for s in tel.registry.spans.items() if s.name == "admit"]
+    assert [s.dur for s in admit] == pytest.approx([0.25])
+
+
+def test_telemetry_does_not_keep_the_engine_alive():
+    import gc
+    import weakref
+
+    tel = EngineTelemetry(attach=False, energy=False)
+    eng = ServingEngine({"a": FakeLane(2)}, TierRouter(_fake_tiers(("a",))),
+                        telemetry=tel)
+    eng.run([_req(0, max_new=2)], clock=SimClock())
+    gone = weakref.ref(eng)
+    del eng
+    gc.collect()
+    assert gone() is None
