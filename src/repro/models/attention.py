@@ -277,7 +277,7 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
                     q_chunk: int = 1024, kv_chunk: int = 1024,
                     positions=None, cache: Optional[dict] = None,
                     x_kv=None, is_cross: bool = False, valid=None,
-                    append: bool = False):
+                    append: bool = False, layer=None):
     """Full attention sub-block (projections + SDPA [+ cache update]).
 
     Training/prefill: cache=None -> returns (y, new_cache_or_None);
@@ -298,6 +298,12 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     pos..pos+K-1 and query i attends causally through position pos+i,
     exactly the KV view K sequential single-token steps would build.
     Dense causal attention only (no window ring, no cross stream).
+
+    layer: decode into the scanned body's stacked pool (DESIGN.md §10).
+    ``cache["k"]``/``["v"]`` are then the (L, B, T, KH*D) buffers the
+    layer scan carries, ``cache["pos"]`` is this layer's fill level, and
+    only the new rows are written, at ``layer``, in place; attention
+    reads that layer back.  Dense non-window self-attention only.
     """
     b, s, d = x.shape
     if positions is None:
@@ -346,7 +352,7 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
                 "append (multi-token) decode supports dense causal "
                 "self-attention only")
         pos = cache["pos"]
-        t = cache["k"].shape[1]
+        t = cache["k"].shape[-2]
         per_slot = getattr(pos, "ndim", 0) > 0
         kf = k.reshape(b, s, kh_d).astype(cache["k"].dtype)
         vf = v.reshape(b, s, kh_d).astype(cache["v"].dtype)
@@ -356,23 +362,20 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
             slot = pos[:, None] + off[None, :]            # (B, K)
             # past-max_len slots (a slot whose budget ends mid-draft)
             # are dropped by the scatter, never clamped onto live rows
-            ck = cache["k"].at[jnp.arange(b)[:, None], slot].set(
-                kf, mode="drop")
-            cv = cache["v"].at[jnp.arange(b)[:, None], slot].set(
-                vf, mode="drop")
             kv_ok = tpos[None, None, :] <= slot[:, :, None]   # (B, K, t)
             vmask = kv_ok[:, None, None, :, :]
         else:
-            ck = jax.lax.dynamic_update_slice(cache["k"], kf, (0, pos, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], vf, (0, pos, 0))
+            slot = pos
             qpos = pos + off
             kv_ok = tpos[None, :] <= qpos[:, None]            # (K, t)
             vmask = kv_ok[None, None, None, :, :]
+        ck = _put_kv(cache["k"], kf, slot, layer)
+        cv = _put_kv(cache["v"], vf, slot, layer)
         new_cache = {"k": ck, "v": cv, "pos": pos + s}
         kh = n_kv_heads
         g = n_heads // kh
-        ck4 = ck.reshape(b, t, kh, head_dim)
-        cv4 = cv.reshape(b, t, kh, head_dim)
+        ck4 = _layer_view(ck, layer).reshape(b, t, kh, head_dim)
+        cv4 = _layer_view(cv, layer).reshape(b, t, kh, head_dim)
         qg = q.reshape(b, s, kh, g, head_dim).astype(ck.dtype)
         s_ = jnp.einsum("bqkgd,btkd->bkgqt", qg, ck4
                         ).astype(jnp.float32) / (head_dim ** 0.5)
@@ -431,7 +434,7 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     # serving (each slot at its own fill level); the vector path scatters
     # per-slot and builds a per-slot validity mask.
     pos = cache["pos"]
-    t = cache["k"].shape[1]
+    t = cache["k"].shape[-2]
     per_slot = getattr(pos, "ndim", 0) > 0
     if not is_cross:
         if window is not None:        # ring buffer for local attention
@@ -442,21 +445,15 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
         vf = v.reshape(b, 1, kh_d).astype(cache["v"].dtype)
         tpos = jnp.arange(t)
         if per_slot:
-            bidx = jnp.arange(b)
-            # out-of-range slots (an idle lane slot past max_len) are
-            # dropped by the scatter, never clamped onto live entries
-            ck = cache["k"].at[bidx, slot].set(kf[:, 0],
-                                               mode="drop")
-            cv = cache["v"].at[bidx, slot].set(vf[:, 0],
-                                               mode="drop")
             if window is not None:
                 age = (slot[:, None] - tpos[None, :]) % t
                 kv_ok = age < jnp.minimum(pos + 1, t)[:, None]
             else:
                 kv_ok = tpos[None, :] <= pos[:, None]          # (B, t)
+            # out-of-range slots (an idle lane slot past max_len) are
+            # dropped by the scatter, never clamped onto live entries
+            slot = slot[:, None]
         else:
-            ck = jax.lax.dynamic_update_slice(cache["k"], kf, (0, slot, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], vf, (0, slot, 0))
             if window is not None:
                 # ring slot i was written `age` steps ago; valid iff among
                 # the last min(pos+1, t) writes
@@ -464,6 +461,8 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
                 kv_ok = age < jnp.minimum(pos + 1, t)
             else:
                 kv_ok = tpos <= pos
+        ck = _put_kv(cache["k"], kf, slot, layer)
+        cv = _put_kv(cache["v"], vf, slot, layer)
         new_cache = {"k": ck, "v": cv, "pos": pos + 1}
     else:
         # cross-attention decode: encoder KV is static (filled at prefill)
@@ -477,8 +476,8 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     g = n_heads // kh
     # bf16 math with f32 accumulation: an f32 cast of the 32k cache would
     # materialize (and reshard) the whole cache every step
-    ck4 = ck.reshape(b, t, kh, head_dim)
-    cv4 = cv.reshape(b, t, kh, head_dim)
+    ck4 = _layer_view(ck, layer).reshape(b, t, kh, head_dim)
+    cv4 = _layer_view(cv, layer).reshape(b, t, kh, head_dim)
     if not is_cross and window is None and _use_cim_attn(ctx.p, is_cross):
         # dense decode: causal(qpos=pos) + fill-level validity reproduce
         # the kv_ok mask exactly; window-ring decode keeps the XLA path
@@ -506,6 +505,30 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     o = o.transpose(0, 3, 1, 2, 4).reshape(b, 1, n_heads, head_dim)
     y = _out_proj(params, o.astype(x.dtype), ctx)
     return y, new_cache
+
+
+def _put_kv(pool, rows, slot, layer=None):
+    """Write new K or V rows (B, S, KH*D) into `pool` at fill level
+    `slot`: a scalar (the whole batch at one position) or (B, S) per-slot
+    indices, scattered with mode="drop" so a slot past T is dropped,
+    never clamped onto a live row.  `pool` is one layer's (B, T, KH*D)
+    cache, or with a `layer` index the stacked (L, B, T, KH*D) body pool,
+    written at that layer in place."""
+    if getattr(slot, "ndim", 0):
+        bidx = jnp.arange(rows.shape[0])[:, None]
+        idx = (bidx, slot) if layer is None else (layer, bidx, slot)
+        return pool.at[idx].set(rows, mode="drop")
+    if layer is None:
+        return jax.lax.dynamic_update_slice(pool, rows, (0, slot, 0))
+    return jax.lax.dynamic_update_slice(pool, rows[None],
+                                        (layer, 0, slot, 0))
+
+
+def _layer_view(pool, layer):
+    """One layer's (B, T, KH*D) cache out of `_put_kv`'s pool."""
+    if layer is None:
+        return pool
+    return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
 
 
 def init_cache(batch: int, max_len: int, n_kv_heads: int, head_dim: int,

@@ -106,13 +106,27 @@ def _init_layer(key, kind: str, cfg: ModelConfig):
     return p
 
 
+def _carries_kv(kind: str, cfg: ModelConfig) -> bool:
+    """Does decode write this kind's KV in place into the stacked pool
+    the layer scan carries (dense, non-window, non-MLA self-attention)?
+    Every other kind's state is small and rides the scan's xs/ys."""
+    return kind in (C.ATTN, ATTN_MOE) and cfg.mla is None
+
+
+def kv_inplace_layers(cfg: ModelConfig) -> int:
+    """Scanned body layers whose KV a compiled decode writes in place."""
+    return cfg.n_periods * sum(_carries_kv(k, cfg) for k in cfg.period)
+
+
 def _apply_layer(params, x, kind: str, cfg: ModelConfig, ctx: CiMContext,
-                 positions, cache, x_aux, valid=None, append=False):
+                 positions, cache, x_aux, valid=None, append=False,
+                 layer=None):
     """Returns (x, new_cache, aux_loss).  `valid` is the optional (B, S)
     ragged-batch mask (pad tokens excluded from self-attention KV; see
     attention_block) — only the self-attention kinds consume it.
     `append` routes the multi-token decode path (speculative verify):
-    dense causal self-attention layers only."""
+    dense causal self-attention layers only.  `layer` indexes the
+    stacked KV pool `cache` holds (`_carries_kv` kinds, decode only)."""
     aux = jnp.float32(0.0)
     h = apply_norm(params["norm1"], x, cfg.norm)
     new_cache = cache
@@ -138,7 +152,8 @@ def _apply_layer(params, x, kind: str, cfg: ModelConfig, ctx: CiMContext,
                 params["attn"], h,
                 causal=(kind != C.ENC_ATTN),
                 window=cfg.window if kind == C.LOCAL else None,
-                cache=cache, valid=valid, append=append, **attn_kw)
+                cache=cache, valid=valid, append=append, layer=layer,
+                **attn_kw)
         x = x + a
     elif kind == C.CROSS:
         a, new_cache = attention_block(params["attn"], h, causal=False,
@@ -392,9 +407,14 @@ class LM:
         return None
 
     def _run_stack(self, params, x, positions, caches, key, x_aux,
-                   valid=None, append=False):
+                   valid=None, append=False, advance=False):
         """Prefix (unrolled) + body (scanned).  caches: None for training,
-        else {"prefix": [...], "body": stacked-pytree}."""
+        else {"prefix": [...], "body": stacked-pytree}.
+
+        advance=True (decode): the body's `_carries_kv` pools ride the
+        scan carry and each layer writes only its new rows into them in
+        place; a donated pool then never leaves its buffer.  Other kinds,
+        and a cache being built (prefill), go through the scan's xs/ys."""
         cfg = self.cfg
         aux_total = jnp.float32(0.0)
         new_prefix = []
@@ -411,37 +431,59 @@ class LM:
             keys = (jax.random.split(jax.random.fold_in(key, 0x5EED), cfg.n_periods)
                     if key is not None else jnp.zeros((cfg.n_periods, 2),
                                                       jnp.uint32))
+            body_caches = None if caches is None else caches["body"]
+            pools = {}
+            if advance:
+                pools = {str(i): body_caches[str(i)]
+                         for i, kind in enumerate(cfg.period)
+                         if _carries_kv(kind, cfg)}
+                body_caches = {i: c for i, c in body_caches.items()
+                               if i not in pools}
 
             def step(carry, xs):
-                h = carry
-                lp, k, cache_in = xs
+                h, pools = carry
+                lp, k, li, cache_in = xs
                 aux_l = jnp.float32(0.0)
-                cache_out = cache_in
+                pools = dict(pools)
+                cache_out = None if cache_in is None else dict(cache_in)
                 for i, kind in enumerate(cfg.period):
                     ctx = CiMContext(
                         self.cim,
                         None if key is None else jax.random.fold_in(k, i))
-                    ci = None if cache_in is None else cache_in[str(i)]
-                    h, c2, aux = _apply_layer(lp[str(i)], h, kind, cfg, ctx,
-                                              positions, ci, x_aux, valid,
-                                              append)
-                    if cache_in is not None:
-                        cache_out = dict(cache_out)
-                        cache_out[str(i)] = c2
+                    pool = pools.get(str(i))
+                    if pool is not None:
+                        ci = dict(pool, pos=pool["pos"][li])
+                        h, c2, aux = _apply_layer(lp[str(i)], h, kind, cfg,
+                                                  ctx, positions, ci, x_aux,
+                                                  valid, append, layer=li)
+                        pools[str(i)] = dict(
+                            c2, pos=pool["pos"].at[li].set(c2["pos"]))
+                    else:
+                        ci = None if cache_in is None else cache_in[str(i)]
+                        h, c2, aux = _apply_layer(lp[str(i)], h, kind, cfg,
+                                                  ctx, positions, ci, x_aux,
+                                                  valid, append)
+                        if cache_in is not None:
+                            cache_out[str(i)] = c2
                     aux_l += aux
-                return h, (cache_out, aux_l)
+                return (h, pools), (cache_out, aux_l)
 
-            step = jax.checkpoint(step) if cfg.remat else step
-            body_caches = None if caches is None else caches["body"]
-            xs = (params["body"], keys, body_caches)
+            # decode never differentiates: no remat around the carried pool
+            step = jax.checkpoint(step) if cfg.remat and not advance else step
+            xs = (params["body"], keys, jnp.arange(cfg.n_periods),
+                  body_caches)
             # the scan body traces ONCE but executes n_periods times:
             # scale MAC attribution so trace-time capture (obs/energy)
             # charges the full stack, not one period
             from repro.core.approx_gemm import obs_mac_scale
 
             with obs_mac_scale(cfg.n_periods):
-                x, (new_body, auxes) = jax.lax.scan(step, x, xs)
+                (x, pools), (new_body, auxes) = jax.lax.scan(
+                    step, (x, pools), xs)
             aux_total += auxes.sum()
+            if advance:
+                new_body = {str(i): pools.get(str(i), new_body.get(str(i)))
+                            for i in range(len(cfg.period))}
         return x, {"prefix": new_prefix, "body": new_body}, aux_total
 
     # ---- training -------------------------------------------------------
@@ -567,7 +609,7 @@ class LM:
                      else jnp.full((b, 1), pos, jnp.int32))
         x = self._embed_decode(params, tokens, positions)
         x, caches, _ = self._run_stack(params, x, positions, caches, key,
-                                       None)
+                                       None, advance=True)
         return self._logits(params, x), caches
 
     def decode_multi(self, params, caches, tokens, pos, key=None):
@@ -589,7 +631,7 @@ class LM:
                      else jnp.broadcast_to(pos + off, (b, kk)))
         x = self._embed_decode(params, tokens, positions)
         x, caches, _ = self._run_stack(params, x, positions, caches, key,
-                                       None, append=True)
+                                       None, append=True, advance=True)
         return self._logits(params, x), caches
 
     def _embed_decode(self, params, tokens, positions):

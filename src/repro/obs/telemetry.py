@@ -120,6 +120,9 @@ class EngineTelemetry:
         self.nmed_g = r.gauge(
             "repro_serving_sentinel_nmed",
             "rolling logit NMED per sentinel lane")
+        self.kv_inplace_g = r.gauge(
+            "repro_serving_kv_inplace_layers",
+            "layers whose KV the lane's compiled decode writes in place")
         self.energy_g = r.gauge(
             "repro_serving_energy_joules",
             "estimated energy attributed per lane")
@@ -213,6 +216,13 @@ class EngineTelemetry:
         return {tid: key for key, tid in self._tids.items()}
 
     # -- engine lifecycle ---------------------------------------------------
+    def on_lane(self, lane: str, backend) -> None:
+        """Once per lane, when the engine is built: what its config
+        fixes about the compiled executables."""
+        n = getattr(backend, "kv_inplace_layers", None)
+        if n is not None:
+            self.kv_inplace_g.set(n, lane=lane)
+
     def on_warmup(self, engine) -> None:
         """Build the per-lane energy meters (eval_shape MAC profiling;
         cheap, abstract).  MUST run before the engine arms its

@@ -250,6 +250,8 @@ class LMLaneBackend:
         import jax
         import jax.numpy as jnp
 
+        from repro.models.transformer import kv_inplace_layers
+
         check_engine_arch(lm.cfg)
         self.lm, self.params = lm, params
         self.mesh = mesh
@@ -287,6 +289,8 @@ class LMLaneBackend:
         self.last_prefill_logits: Optional[np.ndarray] = None
         self.last_decode_logits: Optional[np.ndarray] = None
         self.telemetry = None        # obs.EngineTelemetry, set by the engine
+        # the decode below writes these layers' KV into the pool in place
+        self.kv_inplace_layers = kv_inplace_layers(lm.cfg)
 
         # max_len must be a trace-time constant (it sizes the group
         # caches), so it is closed over — same trick as launch/serve.py
@@ -520,6 +524,8 @@ class ServingEngine:
         for lane in self.lanes.values():
             if hasattr(lane.backend, "telemetry"):
                 lane.backend.telemetry = telemetry
+            if telemetry is not None:
+                telemetry.on_lane(lane.name, lane.backend)
         if telemetry is not None:
             # the telemetry reads this engine's clock without keeping
             # the engine (and its KV pools) alive

@@ -5,10 +5,13 @@ v5e chip (`jax.experimental.topologies`): nothing runs, but the TPU
 compiler accepts or refuses the kernel exactly as it would on the chip,
 and the compiled text holds the Mosaic kernel (`tpu_custom_call`).
 Every block the autotuner can hand the kernel at those shapes is
-compiled.  The topology is described in a module fixture, never at
-import, so test collection stays identical on every worker.
+compiled.  The lane's donated decode is compiled the same way at
+published widths, from abstract shapes, to hold its KV pool in place.
+The topology is described in a module fixture, never at import, so
+test collection stays identical on every worker.
 """
 
+import dataclasses
 import itertools
 
 import jax
@@ -16,10 +19,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core import autotune
 from repro.core.approx_gemm import registered_kernels
 from repro.kernels.cim_gemm import cim_gemm_fused
 from repro.kernels.mitchell_gemm import mitchell_matmul_fused
+from repro.models.transformer import LM
+from repro.serving.tiers import build_tiers
 
 # qwen3-1.7b: decode of 8 slots through wi/wg (2048 -> 6144) and a
 # prefill of 4 x 256 tokens through wo (6144 -> 2048)
@@ -107,3 +113,43 @@ def test_every_tpu_block_obeys_the_tiling_rule():
                 assert bm % 8 == 0 or bm >= m, (kernel, m, bm)
                 assert bk % 128 == 0 or bk >= k, (kernel, k, bk)
                 assert bn % 128 == 0 or bn >= n, (kernel, n, bn)
+
+
+# served decode pools: stablelm-2-1.6b's 6 slots and qwen3-1.7b's 8, each
+# 4096 deep, on the exact tier
+DECODE_POOLS = {"stablelm-1.6b": (6, 4096), "qwen3-1.7b": (8, 4096)}
+
+
+@pytest.mark.parametrize("arch", DECODE_POOLS)
+def test_lane_decode_writes_kv_in_place(one_chip, arch):
+    """The lane's donated decode writes its new K/V rows into the
+    stacked pool in place: its scratch is under one layer's K+V, and no
+    copy or fresh buffer of the pool's shape is in the compiled text (a
+    pool fed through the layer scan's xs/ys took 5.45 GB of scratch at
+    stablelm's widths, two fresh pools and two whole-pool copies)."""
+    slots, depth = DECODE_POOLS[arch]
+    tier = build_tiers(mode="surrogate_fast", families=("exact",))[0]
+    lm = LM(dataclasses.replace(get_config(arch), cim=tier.cim))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lm.init, jax.random.PRNGKey(0)))
+    caches = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: lm.init_caches(slots, depth, per_slot=True)))
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm.decode_step, donate_argnums=(1,)).lower(
+        params, caches, tok, pos).compile()
+
+    cfg = lm.cfg
+    khd = cfg.n_kv_heads * cfg.head_dim_
+    layer_kv = 2 * slots * depth * khd * 2          # bf16 K and V
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_kv
+    pool = f"bf16[{cfg.n_periods},{slots},{depth},{khd}]"
+    moved = [ln for ln in compiled.as_text().splitlines()
+             if pool in ln and any(op in ln for op in
+                                   (" copy(", " copy-start(",
+                                    "AllocateBuffer"))]
+    assert not moved, moved[:2]
