@@ -17,8 +17,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import (CiMContext, Param, apply_rope, cim_linear, param,
-                     rms_norm, rope_tables)
+from .common import (CiMContext, Param, _ambient_mesh, apply_rope,
+                     cim_linear, param, rms_norm, rope_tables)
 
 NEG_INF = -1e30
 
@@ -211,8 +211,6 @@ def _use_cim_attn(p, is_cross: bool) -> bool:
     attention only, and never under an ambient mesh — the mesh lanes
     shard the projections but attention stays per-device (DESIGN.md
     §13 lists cross-attention / mesh as oracle-fallback geometries)."""
-    from .common import _ambient_mesh
-
     return (getattr(p, "attn", False)
             and p.mode in ("hardware", "bit_exact")
             and not is_cross and _ambient_mesh() is None)
@@ -302,8 +300,11 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     layer: decode into the scanned body's stacked pool (DESIGN.md §10).
     ``cache["k"]``/``["v"]`` are then the (L, B, T, KH*D) buffers the
     layer scan carries, ``cache["pos"]`` is this layer's fill level, and
-    only the new rows are written, at ``layer``, in place; attention
-    reads that layer back.  Dense non-window self-attention only.
+    only the new rows are written, at ``layer``, in place.  Dense
+    non-window self-attention only.  Single-token decode lowered for a
+    TPU then attends with `kernels.decode_attn`, which reads the layer
+    where it lies up to each slot's fill level (float and surrogate
+    modes, no ambient mesh); elsewhere attention reads the layer back.
     """
     b, s, d = x.shape
     if positions is None:
@@ -474,10 +475,12 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
         new_cache = cache
     kh = n_kv_heads
     g = n_heads // kh
-    # bf16 math with f32 accumulation: an f32 cast of the 32k cache would
-    # materialize (and reshard) the whole cache every step
-    ck4 = _layer_view(ck, layer).reshape(b, t, kh, head_dim)
-    cv4 = _layer_view(cv, layer).reshape(b, t, kh, head_dim)
+
+    def view(pool):
+        # bf16 math with f32 accumulation: an f32 cast of the 32k cache
+        # would materialize (and reshard) the whole cache every step
+        return _layer_view(pool, layer).reshape(b, t, kh, head_dim)
+
     if not is_cross and window is None and _use_cim_attn(ctx.p, is_cross):
         # dense decode: causal(qpos=pos) + fill-level validity reproduce
         # the kv_ok mask exactly; window-ring decode keeps the XLA path
@@ -487,24 +490,46 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
         kpos_d = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
         kval_d = kv_ok if kv_ok.ndim == 2 else \
             jnp.broadcast_to(kv_ok, (b, t))
-        o = _cim_sdpa(q, ck4, cv4, ctx.p, causal=True, window=None,
-                      qpos=qpos_d, kpos=kpos_d, kval=kval_d)
+        o = _cim_sdpa(q, view(ck), view(cv), ctx.p, causal=True,
+                      window=None, qpos=qpos_d, kpos=kpos_d, kval=kval_d)
         if o is not None:
             return _out_proj(params, o.astype(x.dtype), ctx), new_cache
-    qg = q.reshape(b, 1, kh, g, head_dim).astype(ck.dtype)
-    # NB: bf16 einsums + f32 softmax — XLA:CPU cannot *execute*
-    # bf16xbf16->f32 dots, and TPU MXUs accumulate bf16 dots in f32
-    # internally anyway
-    s_ = jnp.einsum("bqkgd,btkd->bkgqt", qg, ck4).astype(jnp.float32) \
-        / (head_dim ** 0.5)
-    vmask = (kv_ok[:, None, None, None, :] if kv_ok.ndim == 2
-             else kv_ok[None, None, None, None, :])
-    s_ = jnp.where(vmask, s_, NEG_INF)
-    p = jax.nn.softmax(s_, axis=-1).astype(cv.dtype)
-    o = jnp.einsum("bkgqt,btkd->bkgqd", p, cv4)
-    o = o.transpose(0, 3, 1, 2, 4).reshape(b, 1, n_heads, head_dim)
+
+    def xla_sdpa():
+        ck4, cv4 = view(ck), view(cv)
+        qg = q.reshape(b, 1, kh, g, head_dim).astype(ck.dtype)
+        # NB: bf16 einsums + f32 softmax — XLA:CPU cannot *execute*
+        # bf16xbf16->f32 dots, and TPU MXUs accumulate bf16 dots in f32
+        # internally anyway
+        s_ = jnp.einsum("bqkgd,btkd->bkgqt", qg, ck4).astype(
+            jnp.float32) / (head_dim ** 0.5)
+        vmask = (kv_ok[:, None, None, None, :] if kv_ok.ndim == 2
+                 else kv_ok[None, None, None, None, :])
+        s_ = jnp.where(vmask, s_, NEG_INF)
+        p = jax.nn.softmax(s_, axis=-1).astype(cv.dtype)
+        o = jnp.einsum("bkgqt,btkd->bkgqd", p, cv4)
+        return o.transpose(0, 3, 1, 2, 4).reshape(b, 1, n_heads, head_dim)
+
+    if (layer is not None and not is_cross and window is None
+            and not _use_cim_attn(ctx.p, is_cross)
+            and _ambient_mesh() is None):
+        o = _pool_decode_sdpa(q, ck, cv, layer, pos, xla_sdpa)
+    else:
+        o = xla_sdpa()
     y = _out_proj(params, o.astype(x.dtype), ctx)
     return y, new_cache
+
+
+def _pool_decode_sdpa(q, ck, cv, layer, pos, xla_sdpa):
+    """Decode attention over layer `layer` of the carried pools: the
+    `kernels.decode_attn` Pallas kernel where the decode is lowered for
+    a TPU (it reads the layer where it lies, up to each slot's fill
+    level), `xla_sdpa()` on every other platform."""
+    from repro.kernels.decode_attn import pool_decode_attention
+
+    return jax.lax.platform_dependent(
+        tpu=lambda: pool_decode_attention(q, ck, cv, layer, pos),
+        default=xla_sdpa)
 
 
 def _put_kv(pool, rows, slot, layer=None):

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from . import config as C
-from .attention import attention_block, init_attention, init_cache
+from .attention import (_use_cim_attn, attention_block, init_attention,
+                        init_cache)
 from .common import (CiMContext, CiMParams, Param, apply_mlp, apply_norm,
                      cim_linear, init_mlp, init_norm, param, unbox, wsc)
 from .config import ModelConfig
@@ -116,6 +117,18 @@ def _carries_kv(kind: str, cfg: ModelConfig) -> bool:
 def kv_inplace_layers(cfg: ModelConfig) -> int:
     """Scanned body layers whose KV a compiled decode writes in place."""
     return cfg.n_periods * sum(_carries_kv(k, cfg) for k in cfg.period)
+
+
+def decode_attn_kernel_layers(cfg: ModelConfig, cim: CiMParams,
+                              platform: str) -> int:
+    """Carried layers whose single-token decode attention runs
+    `kernels.decode_attn` when the decode is lowered for `platform`
+    with no ambient mesh (`attention.attention_block`'s routing): all
+    of them on a TPU, unless the CiM attention kernels take attention
+    (`cim`, the LM's params); none elsewhere."""
+    if platform != "tpu" or _use_cim_attn(cim, False):
+        return 0
+    return kv_inplace_layers(cfg)
 
 
 def _apply_layer(params, x, kind: str, cfg: ModelConfig, ctx: CiMContext,

@@ -250,7 +250,9 @@ class LMLaneBackend:
         import jax
         import jax.numpy as jnp
 
-        from repro.models.transformer import kv_inplace_layers
+        from repro.kernels.decode_attn import kv_block
+        from repro.models.transformer import (decode_attn_kernel_layers,
+                                              kv_inplace_layers)
 
         check_engine_arch(lm.cfg)
         self.lm, self.params = lm, params
@@ -291,6 +293,14 @@ class LMLaneBackend:
         self.telemetry = None        # obs.EngineTelemetry, set by the engine
         # the decode below writes these layers' KV into the pool in place
         self.kv_inplace_layers = kv_inplace_layers(lm.cfg)
+        # ... and, lowered for a TPU with no mesh, attends over them with
+        # kernels.decode_attn, `_kv_rows` pool rows per block
+        self.decode_attn_kernel_layers = 0 if mesh is not None else \
+            decode_attn_kernel_layers(lm.cfg, lm.cim, jax.default_backend())
+        cfg = lm.cfg
+        self._kv_rows = kv_block(self.max_len, cfg.n_kv_heads * cfg.head_dim_,
+                                 cfg.head_dim_,
+                                 jnp.dtype(jnp.bfloat16).itemsize)[0]
 
         # max_len must be a trace-time constant (it sizes the group
         # caches), so it is closed over — same trick as launch/serve.py
@@ -407,6 +417,17 @@ class LMLaneBackend:
             self.slot_tokens[sl] = first[i]
             self.slot_pos[sl] = lens[i]
         return first[:g]
+
+    def decode_kv_rows(self) -> Tuple[int, int]:
+        """(read, pool): the pool rows the next decode round's attention
+        reads through `kernels.decode_attn` (each slot's live rows,
+        rounded up to whole blocks, in every kernel layer), and the rows
+        its carried layers hold (slots x max_len each)."""
+        from repro.kernels.decode_attn import live_rows
+
+        read = live_rows(self.slot_pos, self.max_len, self._kv_rows).sum()
+        return (self.decode_attn_kernel_layers * int(read),
+                self.kv_inplace_layers * self.n_slots * self.max_len)
 
     def decode_round(self) -> np.ndarray:
         """One greedy decode step for the whole pool (idle slots ride
@@ -785,13 +806,17 @@ class ServingEngine:
                 if tel is None:
                     nxt = lane.backend.decode_round()
                 else:
+                    kv_rows = (lane.backend.decode_kv_rows()
+                               if hasattr(lane.backend, "decode_kv_rows")
+                               else None)
                     with tel.span("decode_round", lane.name,
                                   rows=lane.backend.n_slots,
                                   useful=len(lane.running)) as sp:
                         nxt = lane.backend.decode_round()
                     tel.on_decode_round(
                         lane.name, [r.result.rid for r in
-                                    lane.running.values()], sp.dur)
+                                    lane.running.values()], sp.dur,
+                        kv_rows)
             except LaneHealthError as e:
                 if sen is None:
                     raise

@@ -12,8 +12,13 @@ kind keeps the scan's xs/ys.  Held here, at smoke widths on the CPU:
   * the returned cache tree has `init_caches`' structure, shapes and
     dtypes;
   * each lane reports its carried layer count on
-    `repro_serving_kv_inplace_layers`.
+    `repro_serving_kv_inplace_layers`, the layers the `decode_attn`
+    kernel serves on `repro_serving_decode_attn_kernel_layers` (0 on
+    the CPU), and each decode round's KV pool rows on
+    `repro_serving_decode_kv_rows_total`.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -23,10 +28,11 @@ import pytest
 from repro.configs import get_config
 from repro.models.transformer import LM, kv_inplace_layers
 from repro.obs import EngineTelemetry, prometheus_text
-from repro.serving import ServingEngine
+from repro.serving import ServingEngine, SimClock, build_engine
 from repro.serving.engine import LMLaneBackend
 from repro.serving.tiers import TierRouter, build_tiers
 from test_serve_consistency import _batch_for
+from test_serving import _req
 
 B, S, MAX_LEN = 2, 12, 20
 
@@ -116,3 +122,43 @@ def test_lane_reports_kv_inplace_layers():
     assert tel.kv_inplace_g.value(lane=tier.name) == 24
     assert ('repro_serving_kv_inplace_layers{lane="exact"} 24'
             in prometheus_text(tel.registry))
+
+
+def test_cpu_lane_decode_kv_rows():
+    """A CPU lane's decode attends on the XLA path: the kernel gauge
+    reads 0, and every decode round adds 0 rows read by the kernel and
+    slots x max_len per carried layer to the pool rows."""
+    cfg = get_config("qwen3-1.7b", smoke=True)
+    tel = EngineTelemetry(attach=False, energy=False)
+    eng = build_engine(cfg, tiers=build_tiers(families=("exact",)),
+                       slots_per_tier=2, max_len=32, prompt_buckets=(8,),
+                       group_buckets=(1, 2), telemetry=tel)
+    eng.run([_req(i, tier="exact", plen=5 + i, max_new=3)
+             for i in range(3)], clock=SimClock())
+    lane = "exact"
+    rounds = tel.decode_rounds_c.value(tier=lane)
+    assert rounds > 0
+    assert tel.decode_attn_kernel_g.value(lane=lane) == 0
+    assert tel.decode_kv_rows_c.value(lane=lane, kind="read") == 0
+    assert (tel.decode_kv_rows_c.value(lane=lane, kind="pool")
+            == rounds * kv_inplace_layers(cfg) * 2 * 32)
+
+
+def test_decode_kv_rows_from_fill_levels():
+    """Rows read by the kernel are each slot's live rows (its fill
+    level + 1, at most max_len) rounded up to whole 256-row blocks at
+    stablelm's 32 x 64 heads, in every kernel layer (here all the
+    carried layers, as on a TPU); the counter adds them up per round."""
+    cfg = dataclasses.replace(get_config("stablelm-1.6b"), n_layers=2,
+                              n_periods=2)
+    lane = LMLaneBackend(LM(cfg), None, n_slots=3, max_len=768,
+                         prompt_buckets=(16,), group_buckets=(1,))
+    assert lane.decode_attn_kernel_layers == 0          # the CPU
+    lane.decode_attn_kernel_layers = lane.kv_inplace_layers
+    lane.slot_pos[:] = [0, 300, 767]                    # 1, 301, 768 live
+    assert lane.decode_kv_rows() == (2 * (256 + 512 + 768), 2 * 3 * 768)
+    tel = EngineTelemetry(attach=False, energy=False)
+    for _ in range(2):
+        tel.on_decode_round("exact", [], 0.0, lane.decode_kv_rows())
+    assert tel.decode_kv_rows_c.value(lane="exact", kind="read") == 6144
+    assert tel.decode_kv_rows_c.value(lane="exact", kind="pool") == 9216

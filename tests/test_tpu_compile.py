@@ -6,13 +6,16 @@ compiler accepts or refuses the kernel exactly as it would on the chip,
 and the compiled text holds the Mosaic kernel (`tpu_custom_call`).
 Every block the autotuner can hand the kernel at those shapes is
 compiled.  The lane's donated decode is compiled the same way at
-published widths, from abstract shapes, to hold its KV pool in place.
+published widths, from abstract shapes, to hold its KV pool in place
+and to attend with the `decode_attn` kernel where the pool lies.
 The topology is described in a module fixture, never at import, so
 test collection stays identical on every worker.
 """
 
 import dataclasses
 import itertools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,13 +123,9 @@ def test_every_tpu_block_obeys_the_tiling_rule():
 DECODE_POOLS = {"stablelm-1.6b": (6, 4096), "qwen3-1.7b": (8, 4096)}
 
 
-@pytest.mark.parametrize("arch", DECODE_POOLS)
-def test_lane_decode_writes_kv_in_place(one_chip, arch):
-    """The lane's donated decode writes its new K/V rows into the
-    stacked pool in place: its scratch is under one layer's K+V, and no
-    copy or fresh buffer of the pool's shape is in the compiled text (a
-    pool fed through the layer scan's xs/ys took 5.45 GB of scratch at
-    stablelm's widths, two fresh pools and two whole-pool copies)."""
+def _lane_decode(one_chip, arch):
+    """The exact lane's donated decode at `DECODE_POOLS[arch]`, compiled
+    for the described chip from abstract shapes."""
     slots, depth = DECODE_POOLS[arch]
     tier = build_tiers(mode="surrogate_fast", families=("exact",))[0]
     lm = LM(dataclasses.replace(get_config(arch), cim=tier.cim))
@@ -142,8 +141,18 @@ def test_lane_decode_writes_kv_in_place(one_chip, arch):
     pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(lm.decode_step, donate_argnums=(1,)).lower(
         params, caches, tok, pos).compile()
+    return compiled, lm.cfg
 
-    cfg = lm.cfg
+
+@pytest.mark.parametrize("arch", DECODE_POOLS)
+def test_lane_decode_writes_kv_in_place(one_chip, arch):
+    """The lane's donated decode writes its new K/V rows into the
+    stacked pool in place: its scratch is under one layer's K+V, and no
+    copy or fresh buffer of the pool's shape is in the compiled text (a
+    pool fed through the layer scan's xs/ys took 5.45 GB of scratch at
+    stablelm's widths, two fresh pools and two whole-pool copies)."""
+    slots, depth = DECODE_POOLS[arch]
+    compiled, cfg = _lane_decode(one_chip, arch)
     khd = cfg.n_kv_heads * cfg.head_dim_
     layer_kv = 2 * slots * depth * khd * 2          # bf16 K and V
     assert compiled.memory_analysis().temp_size_in_bytes < layer_kv
@@ -153,3 +162,26 @@ def test_lane_decode_writes_kv_in_place(one_chip, arch):
                                    (" copy(", " copy-start(",
                                     "AllocateBuffer"))]
     assert not moved, moved[:2]
+
+
+@pytest.mark.parametrize("arch", DECODE_POOLS)
+def test_lane_decode_attends_in_the_pool(one_chip, arch):
+    """The lane's decode attends with the `decode_attn` kernel, which
+    reads each layer's K and V where they lie in the carried pool: the
+    compiled text holds the kernel, and no instruction (slice, copy or
+    fusion) produces a buffer of one layer's shape (the XLA decode
+    path slices each layer's K and V out of the pool, then copies them
+    to the attention dot's layout)."""
+    slots, depth = DECODE_POOLS[arch]
+    compiled, cfg = _lane_decode(one_chip, arch)
+    txt = compiled.as_text()
+    assert any("tpu_custom_call" in ln and "decode_attn" in ln
+               for ln in txt.splitlines())
+    layer = slots * depth * cfg.n_kv_heads * cfg.head_dim_
+    made = []
+    for ln in txt.splitlines():
+        out = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [a-z][\w-]*\(", ln)
+        dims = re.findall(r"bf16\[([\d,]+)\]", out[1] if out else "")
+        if any(math.prod(map(int, d.split(","))) == layer for d in dims):
+            made.append(ln)
+    assert not made, made[:2]
