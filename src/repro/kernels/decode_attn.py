@@ -35,7 +35,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -76,14 +75,6 @@ def kv_block(t: int, khd: int, d: int, itemsize: int):
     and ``khd = KH*D`` wide, of ``itemsize``-byte elements."""
     lanes = _head_block_lanes(khd, d)
     return _kv_block_rows(t, lanes, itemsize), lanes
-
-
-def live_rows(pos, t: int, rows: int) -> np.ndarray:
-    """Pool rows the kernel reads for slots at fill levels ``pos``:
-    each slot's ``min(pos + 1, t)`` live rows rounded up to whole
-    ``rows`` blocks."""
-    live = np.minimum(np.asarray(pos, np.int64) + 1, t)
-    return -(-live // rows) * rows
 
 
 def _kernel(li_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,

@@ -275,12 +275,13 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
                     q_chunk: int = 1024, kv_chunk: int = 1024,
                     positions=None, cache: Optional[dict] = None,
                     x_kv=None, is_cross: bool = False, valid=None,
-                    append: bool = False, layer=None):
+                    append: bool = False, layer=None, slots=None):
     """Full attention sub-block (projections + SDPA [+ cache update]).
 
-    Training/prefill: cache=None -> returns (y, new_cache_or_None);
-    prefill fills `cache` if one is passed (pre-allocated to max length).
-    Decode: x is (B, 1, D) and cache is the running KV state.
+    Training: cache=None -> returns (y, None).  Prefill (S > 1) writes
+    the prompt's K/V into `cache` from position 0, as whole rows: every
+    row past the prompt is zero afterwards.  Decode: x is (B, 1, D) and
+    cache is the running KV state.
 
     valid: optional (B, S) bool mask for ragged (padded) batches.  Pad
     tokens are masked out of the KV axis so no query attends to them,
@@ -297,14 +298,18 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     exactly the KV view K sequential single-token steps would build.
     Dense causal attention only (no window ring, no cross stream).
 
-    layer: decode into the scanned body's stacked pool (DESIGN.md §10).
+    layer: the scanned body's stacked pool (DESIGN.md §10).
     ``cache["k"]``/``["v"]`` are then the (L, B, T, KH*D) buffers the
     layer scan carries, ``cache["pos"]`` is this layer's fill level, and
-    only the new rows are written, at ``layer``, in place.  Dense
-    non-window self-attention only.  Single-token decode lowered for a
-    TPU then attends with `kernels.decode_attn`, which reads the layer
-    where it lies up to each slot's fill level (float and surrogate
-    modes, no ambient mesh); elsewhere attention reads the layer back.
+    only this layer's rows are written, in place.  Single-token decode
+    lowered for a TPU then attends with `kernels.decode_attn`, which
+    reads the layer where it lies up to each slot's fill level (float
+    and surrogate modes, no ambient mesh); elsewhere attention reads the
+    layer back.
+
+    slots: (B,) the pool rows a prefill's batch rows fill (admission
+    into a slot pool); a slot past the pool, the admission padding, is
+    dropped.  None writes batch row b into pool row b.
     """
     b, s, d = x.shape
     if positions is None:
@@ -323,7 +328,7 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     if valid is not None and x_kv is None and s > 1:
         seq_info = (positions, positions, valid)
 
-    if cache is None:
+    if cache is None or (s > 1 and not append):
         y = None
         if _use_cim_attn(ctx.p, is_cross or x_kv is not None):
             kva = valid if valid is not None else \
@@ -334,7 +339,10 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
             y = _chunked_attn(q, k, v, q_chunk, kv_chunk, causal, window,
                               q_offset=0, kv_len_valid=k.shape[1],
                               seq_info=seq_info)
-        return _out_proj(params, y.astype(x.dtype), ctx), None
+        y = _out_proj(params, y.astype(x.dtype), ctx)
+        if cache is None:
+            return y, None
+        return y, _prefill_cache(cache, k, v, valid, layer, slots)
 
     # caches store K/V flattened to (B, T, KH*D): the flat dim shards
     # cleanly on the model axis (KH alone rarely divides it), matching
@@ -342,182 +350,129 @@ def attention_block(params, x, *, n_heads, n_kv_heads, head_dim,
     # cache it inserted a full cache reshard EVERY decode step
     # (69 GB/token at llama-11B 32k, EXPERIMENTS.md §Perf)
     kh_d = n_kv_heads * head_dim
-    if append:
-        # multi-token decode append (speculative verify).  Keys/values
-        # for all K tokens scatter in at pos..pos+K-1; the per-query
-        # causal mask `tpos <= pos + i` gives query i exactly the KV
-        # window sequential decoding would have seen (later in-flight
-        # keys are written but masked — a softmax weight of exactly 0).
-        if is_cross or window is not None or not causal:
+    # cache["pos"] is a scalar for lockstep batches (every sequence at
+    # the same position) or a (B,) vector for slot-pool serving (each
+    # slot at its own fill level); qpos is each query's position, (S,)
+    # or (B, S), and every mask below broadcasts to (B, S, T)
+    pos = cache["pos"]
+    t = cache["k"].shape[-2]
+    tpos = jnp.arange(t)
+    per_slot = getattr(pos, "ndim", 0) > 0
+    qpos = (pos[:, None] if per_slot else pos) + jnp.arange(s)
+    if is_cross:
+        # cross-attention decode: encoder KV is static (filled at prefill)
+        ck, cv, new_cache = cache["k"], cache["v"], cache
+        kv_ok = tpos < (pos[:, None, None] if per_slot else pos)
+    else:
+        if append and (window is not None or not causal):
             raise NotImplementedError(
                 "append (multi-token) decode supports dense causal "
                 "self-attention only")
-        pos = cache["pos"]
-        t = cache["k"].shape[-2]
-        per_slot = getattr(pos, "ndim", 0) > 0
-        kf = k.reshape(b, s, kh_d).astype(cache["k"].dtype)
-        vf = v.reshape(b, s, kh_d).astype(cache["v"].dtype)
-        tpos = jnp.arange(t)
-        off = jnp.arange(s)
-        if per_slot:
-            slot = pos[:, None] + off[None, :]            # (B, K)
-            # past-max_len slots (a slot whose budget ends mid-draft)
-            # are dropped by the scatter, never clamped onto live rows
-            kv_ok = tpos[None, None, :] <= slot[:, :, None]   # (B, K, t)
-            vmask = kv_ok[:, None, None, :, :]
-        else:
-            slot = pos
-            qpos = pos + off
-            kv_ok = tpos[None, :] <= qpos[:, None]            # (K, t)
-            vmask = kv_ok[None, None, None, :, :]
-        ck = _put_kv(cache["k"], kf, slot, layer)
-        cv = _put_kv(cache["v"], vf, slot, layer)
-        new_cache = {"k": ck, "v": cv, "pos": pos + s}
-        kh = n_kv_heads
-        g = n_heads // kh
-        ck4 = _layer_view(ck, layer).reshape(b, t, kh, head_dim)
-        cv4 = _layer_view(cv, layer).reshape(b, t, kh, head_dim)
-        qg = q.reshape(b, s, kh, g, head_dim).astype(ck.dtype)
-        s_ = jnp.einsum("bqkgd,btkd->bkgqt", qg, ck4
-                        ).astype(jnp.float32) / (head_dim ** 0.5)
-        s_ = jnp.where(vmask, s_, NEG_INF)
-        p = jax.nn.softmax(s_, axis=-1).astype(cv.dtype)
-        o = jnp.einsum("bkgqt,btkd->bkgqd", p, cv4)
-        o = o.transpose(0, 3, 1, 2, 4).reshape(b, s, n_heads, head_dim)
-        return _out_proj(params, o.astype(x.dtype), ctx), new_cache
-
-    if s > 1:  # prefill into a pre-allocated cache
-        t = cache["k"].shape[1]
-        skv = k.shape[1]
-        kf = k.reshape(b, skv, kh_d)
-        vf = v.reshape(b, skv, kh_d)
-        if valid is not None:
-            # zero the pad rows: entries at/past each row's fill level
-            # stay zero, so a rolled-back cache (serving/spec.py) is
-            # byte-identical to one that never drafted.  Attention never
-            # reads them (kv_valid / fill-level masks), so logits are
-            # unchanged.
-            kf = jnp.where(valid[:, :, None], kf, 0)
-            vf = jnp.where(valid[:, :, None], vf, 0)
-        if skv <= t:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], kf.astype(cache["k"].dtype), (0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], vf.astype(cache["v"].dtype), (0, 0, 0))
-        else:  # window ring buffer keeps the last t entries at slot p % t
-            p0 = skv - t
-            ck = jnp.roll(kf[:, p0:].astype(cache["k"].dtype), p0 % t,
-                          axis=1)
-            cv = jnp.roll(vf[:, p0:].astype(cache["v"].dtype), p0 % t,
-                          axis=1)
-        y = None
-        if _use_cim_attn(ctx.p, is_cross):
-            kva = valid if valid is not None else \
-                jnp.ones(positions.shape, jnp.int32)
-            y = _cim_sdpa(q, k, v, ctx.p, causal=causal, window=window,
-                          qpos=positions, kpos=positions, kval=kva)
-        if y is None:
-            y = _chunked_attn(q, k, v, q_chunk, kv_chunk, causal, window,
-                              q_offset=0, kv_len_valid=k.shape[1],
-                              seq_info=seq_info)
-        if valid is not None:
-            # per-slot fill level: pad tokens don't count (right-padded
-            # prompts resume decoding at their true length; see
-            # models/transformer.LM.prefill for the left-pad caveat)
-            pos_out = valid.sum(axis=1).astype(jnp.int32)
-        else:
-            pos_out = jnp.int32(k.shape[1])
-        new_cache = {"k": ck, "v": cv, "pos": pos_out}
-        return _out_proj(params, y.astype(x.dtype), ctx), new_cache
-
-    # single-token decode.  cache["pos"] is a scalar for lockstep batches
-    # (every sequence at the same position) or a (B,) vector for slot-pool
-    # serving (each slot at its own fill level); the vector path scatters
-    # per-slot and builds a per-slot validity mask.
-    pos = cache["pos"]
-    t = cache["k"].shape[-2]
-    per_slot = getattr(pos, "ndim", 0) > 0
-    if not is_cross:
+        # per-slot rows are scattered: a row past max_len (an idle slot,
+        # or a draft past its budget) is dropped, never clamped onto a
+        # live row
+        slot = qpos if per_slot else pos
         if window is not None:        # ring buffer for local attention
-            slot = pos % t
+            slot = slot % t
+        ck = _put_kv(cache["k"], k.reshape(b, s, kh_d).astype(
+            cache["k"].dtype), slot, layer)
+        cv = _put_kv(cache["v"], v.reshape(b, s, kh_d).astype(
+            cache["v"].dtype), slot, layer)
+        new_cache = {"k": ck, "v": cv, "pos": pos + s}
+        if window is None:
+            # query i sees through pos + i: K tokens appended at once
+            # (speculative verify, later in-flight keys written but
+            # weighted exactly 0) attend as K sequential steps would
+            kv_ok = tpos <= qpos[..., None]
         else:
-            slot = pos
-        kf = k.reshape(b, 1, kh_d).astype(cache["k"].dtype)
-        vf = v.reshape(b, 1, kh_d).astype(cache["v"].dtype)
-        tpos = jnp.arange(t)
-        if per_slot:
-            if window is not None:
-                age = (slot[:, None] - tpos[None, :]) % t
-                kv_ok = age < jnp.minimum(pos + 1, t)[:, None]
-            else:
-                kv_ok = tpos[None, :] <= pos[:, None]          # (B, t)
-            # out-of-range slots (an idle lane slot past max_len) are
-            # dropped by the scatter, never clamped onto live entries
-            slot = slot[:, None]
-        else:
-            if window is not None:
-                # ring slot i was written `age` steps ago; valid iff among
-                # the last min(pos+1, t) writes
-                age = (slot - tpos) % t
-                kv_ok = age < jnp.minimum(pos + 1, t)
-            else:
-                kv_ok = tpos <= pos
-        ck = _put_kv(cache["k"], kf, slot, layer)
-        cv = _put_kv(cache["v"], vf, slot, layer)
-        new_cache = {"k": ck, "v": cv, "pos": pos + 1}
-    else:
-        # cross-attention decode: encoder KV is static (filled at prefill)
-        ck, cv = cache["k"], cache["v"]
-        if per_slot:
-            kv_ok = jnp.arange(t)[None, :] < pos[:, None]
-        else:
-            kv_ok = jnp.arange(t) < pos
-        new_cache = cache
-    kh = n_kv_heads
-    g = n_heads // kh
+            # ring slot i was written `age` steps ago; valid iff among
+            # the last min(pos+1, t) writes
+            age = (slot[..., None] - tpos) % t
+            kv_ok = age < jnp.minimum(qpos + 1, t)[..., None]
 
-    def view(pool):
-        # bf16 math with f32 accumulation: an f32 cast of the 32k cache
-        # would materialize (and reshard) the whole cache every step
-        return _layer_view(pool, layer).reshape(b, t, kh, head_dim)
-
-    if not is_cross and window is None and _use_cim_attn(ctx.p, is_cross):
+    single = not append and not is_cross and window is None
+    if single and _use_cim_attn(ctx.p, is_cross):
         # dense decode: causal(qpos=pos) + fill-level validity reproduce
         # the kv_ok mask exactly; window-ring decode keeps the XLA path
         # (ring slot order scrambles the positional coordinates)
-        qpos_d = pos[:, None].astype(jnp.int32) if per_slot else \
-            jnp.full((b, 1), pos, jnp.int32)
-        kpos_d = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        kval_d = kv_ok if kv_ok.ndim == 2 else \
-            jnp.broadcast_to(kv_ok, (b, t))
+        def view(pool):
+            return _layer_view(pool, layer).reshape(b, t, n_kv_heads,
+                                                    head_dim)
+
         o = _cim_sdpa(q, view(ck), view(cv), ctx.p, causal=True,
-                      window=None, qpos=qpos_d, kpos=kpos_d, kval=kval_d)
+                      window=None,
+                      qpos=jnp.broadcast_to(qpos, (b, 1)).astype(jnp.int32),
+                      kpos=jnp.broadcast_to(tpos, (b, t)).astype(jnp.int32),
+                      kval=jnp.broadcast_to(kv_ok, (b, 1, t))[:, 0])
         if o is not None:
             return _out_proj(params, o.astype(x.dtype), ctx), new_cache
 
     def xla_sdpa():
-        ck4, cv4 = view(ck), view(cv)
-        qg = q.reshape(b, 1, kh, g, head_dim).astype(ck.dtype)
-        # NB: bf16 einsums + f32 softmax — XLA:CPU cannot *execute*
-        # bf16xbf16->f32 dots, and TPU MXUs accumulate bf16 dots in f32
-        # internally anyway
-        s_ = jnp.einsum("bqkgd,btkd->bkgqt", qg, ck4).astype(
-            jnp.float32) / (head_dim ** 0.5)
-        vmask = (kv_ok[:, None, None, None, :] if kv_ok.ndim == 2
-                 else kv_ok[None, None, None, None, :])
-        s_ = jnp.where(vmask, s_, NEG_INF)
-        p = jax.nn.softmax(s_, axis=-1).astype(cv.dtype)
-        o = jnp.einsum("bkgqt,btkd->bkgqd", p, cv4)
-        return o.transpose(0, 3, 1, 2, 4).reshape(b, 1, n_heads, head_dim)
+        return _dense_sdpa(q, ck, cv, kv_ok, layer)
 
-    if (layer is not None and not is_cross and window is None
-            and not _use_cim_attn(ctx.p, is_cross)
+    if (single and layer is not None and not _use_cim_attn(ctx.p, is_cross)
             and _ambient_mesh() is None):
         o = _pool_decode_sdpa(q, ck, cv, layer, pos, xla_sdpa)
     else:
         o = xla_sdpa()
     y = _out_proj(params, o.astype(x.dtype), ctx)
     return y, new_cache
+
+
+def _prefill_cache(cache, k, v, valid, layer, slots):
+    """`cache` with a prompt's K/V (B, S, KH, D) written from position 0
+    as whole rows (`_put_kv`), and its fill level."""
+    b, skv = k.shape[:2]
+    t = cache["k"].shape[-2]
+    rows = []
+    for new, pool in ((k, cache["k"]), (v, cache["v"])):
+        new = new.reshape(b, skv, -1).astype(pool.dtype)
+        if valid is not None:
+            # zero the pad rows: entries at/past each row's fill level
+            # stay zero, so a rolled-back cache (serving/spec.py) is
+            # byte-identical to one that never drafted.  Attention never
+            # reads them (kv_valid / fill-level masks), so logits are
+            # unchanged.
+            new = jnp.where(valid[:, :, None], new, 0)
+        if skv > t:  # window ring buffer keeps the last t entries at p % t
+            p0 = skv - t
+            new = jnp.roll(new[:, p0:], p0 % t, axis=1)
+        rows.append(jnp.pad(new, ((0, 0), (0, t - new.shape[1]), (0, 0))))
+    if valid is None:
+        pos = jnp.int32(skv)
+    else:
+        # per-slot fill level: pad tokens don't count (right-padded
+        # prompts resume decoding at their true length; see
+        # models/transformer.LM.prefill for the left-pad caveat)
+        pos = valid.sum(axis=1).astype(jnp.int32)
+        if slots is not None:
+            pos = cache["pos"].at[slots].set(pos, mode="drop")
+    return {"k": _put_kv(cache["k"], rows[0], 0, layer, slots),
+            "v": _put_kv(cache["v"], rows[1], 0, layer, slots), "pos": pos}
+
+
+def _dense_sdpa(q, ck, cv, mask, layer):
+    """XLA softmax attention of q (B, S, H, D) over layer `layer` of the
+    K/V caches (`_layer_view`), at the positions where `mask`
+    (broadcastable to (B, S, T)) holds.
+
+    bf16 einsums with f32 softmax: an f32 cast of a 32k cache would
+    materialize (and reshard) the whole cache every step; XLA:CPU cannot
+    *execute* bf16xbf16->f32 dots, and TPU MXUs accumulate bf16 dots in
+    f32 internally anyway."""
+    b, s, h, d = q.shape
+    t = ck.shape[-2]
+    kh = ck.shape[-1] // d
+    ck4 = _layer_view(ck, layer).reshape(b, t, kh, d)
+    cv4 = _layer_view(cv, layer).reshape(b, t, kh, d)
+    qg = q.reshape(b, s, kh, h // kh, d).astype(ck.dtype)
+    s_ = jnp.einsum("bqkgd,btkd->bkgqt", qg, ck4
+                    ).astype(jnp.float32) / (d ** 0.5)
+    s_ = jnp.where(jnp.broadcast_to(mask, (b, s, t))[:, None, None], s_,
+                   NEG_INF)
+    p = jax.nn.softmax(s_, axis=-1).astype(cv.dtype)
+    o = jnp.einsum("bkgqt,btkd->bkgqd", p, cv4)
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
 
 
 def _pool_decode_sdpa(q, ck, cv, layer, pos, xla_sdpa):
@@ -532,21 +487,26 @@ def _pool_decode_sdpa(q, ck, cv, layer, pos, xla_sdpa):
         default=xla_sdpa)
 
 
-def _put_kv(pool, rows, slot, layer=None):
-    """Write new K or V rows (B, S, KH*D) into `pool` at fill level
-    `slot`: a scalar (the whole batch at one position) or (B, S) per-slot
-    indices, scattered with mode="drop" so a slot past T is dropped,
-    never clamped onto a live row.  `pool` is one layer's (B, T, KH*D)
-    cache, or with a `layer` index the stacked (L, B, T, KH*D) body pool,
-    written at that layer in place."""
+def _put_kv(pool, rows, slot, layer=None, dest=None):
+    """The one write of K or V rows (B, S, KH*D) into a cache.
+
+    `pool` is one layer's (B, T, KH*D) cache, or with a `layer` index
+    the stacked (L, B, T, KH*D) body pool, written at that layer in
+    place.  `slot` is where the rows land: a scalar (the whole batch at
+    one position) or (B, S) per-slot indices, scattered with mode="drop"
+    so a slot past T is dropped, never clamped onto a live row.  `dest`
+    (B,) names the pool rows the batch fills with whole rows (S = T,
+    slot 0); a dest past the pool is dropped the same way.  Without it
+    batch row b lands in pool row b."""
+    lead = () if layer is None else (layer,)
+    if dest is not None:
+        return pool.at[lead + (dest,)].set(rows, mode="drop")
     if getattr(slot, "ndim", 0):
         bidx = jnp.arange(rows.shape[0])[:, None]
-        idx = (bidx, slot) if layer is None else (layer, bidx, slot)
-        return pool.at[idx].set(rows, mode="drop")
-    if layer is None:
-        return jax.lax.dynamic_update_slice(pool, rows, (0, slot, 0))
-    return jax.lax.dynamic_update_slice(pool, rows[None],
-                                        (layer, 0, slot, 0))
+        return pool.at[lead + (bidx, slot)].set(rows, mode="drop")
+    return jax.lax.dynamic_update_slice(
+        pool, rows.reshape((1,) * len(lead) + rows.shape),
+        lead + (0, slot, 0))
 
 
 def _layer_view(pool, layer):

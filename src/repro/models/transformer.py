@@ -8,7 +8,8 @@ remat), so a 61-layer MoE lowers to one compact while loop.
 
 Entry points (all pure functions of (params, batch)):
   * ``loss_fn``      — next-token CE (+ MoE aux, + MTP), for train_step
-  * ``prefill``      — fills pre-allocated caches, returns last logits
+  * ``prefill``      — fills caches (its own, or a slot pool's rows
+                       with ``prefill_into``), returns last logits
   * ``decode_step``  — one token in, one token out, caches updated
 
 The CiM context (the paper's approximate execution) threads through
@@ -24,8 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from . import config as C
-from .attention import (_use_cim_attn, attention_block, init_attention,
-                        init_cache)
+from .attention import attention_block, init_attention, init_cache
 from .common import (CiMContext, CiMParams, Param, apply_mlp, apply_norm,
                      cim_linear, init_mlp, init_norm, param, unbox, wsc)
 from .config import ModelConfig
@@ -108,38 +108,29 @@ def _init_layer(key, kind: str, cfg: ModelConfig):
 
 
 def _carries_kv(kind: str, cfg: ModelConfig) -> bool:
-    """Does decode write this kind's KV in place into the stacked pool
-    the layer scan carries (dense, non-window, non-MLA self-attention)?
-    Every other kind's state is small and rides the scan's xs/ys."""
+    """Does this kind write its KV in place into the stacked pool the
+    layer scan carries (dense, non-window, non-MLA self-attention)?
+    Every other kind's state is small or a ring and rides the scan's
+    xs/ys."""
     return kind in (C.ATTN, ATTN_MOE) and cfg.mla is None
 
 
 def kv_inplace_layers(cfg: ModelConfig) -> int:
-    """Scanned body layers whose KV a compiled decode writes in place."""
+    """Scanned body layers whose KV a compiled prefill or decode writes
+    in place."""
     return cfg.n_periods * sum(_carries_kv(k, cfg) for k in cfg.period)
-
-
-def decode_attn_kernel_layers(cfg: ModelConfig, cim: CiMParams,
-                              platform: str) -> int:
-    """Carried layers whose single-token decode attention runs
-    `kernels.decode_attn` when the decode is lowered for `platform`
-    with no ambient mesh (`attention.attention_block`'s routing): all
-    of them on a TPU, unless the CiM attention kernels take attention
-    (`cim`, the LM's params); none elsewhere."""
-    if platform != "tpu" or _use_cim_attn(cim, False):
-        return 0
-    return kv_inplace_layers(cfg)
 
 
 def _apply_layer(params, x, kind: str, cfg: ModelConfig, ctx: CiMContext,
                  positions, cache, x_aux, valid=None, append=False,
-                 layer=None):
+                 layer=None, slots=None):
     """Returns (x, new_cache, aux_loss).  `valid` is the optional (B, S)
     ragged-batch mask (pad tokens excluded from self-attention KV; see
     attention_block) — only the self-attention kinds consume it.
     `append` routes the multi-token decode path (speculative verify):
     dense causal self-attention layers only.  `layer` indexes the
-    stacked KV pool `cache` holds (`_carries_kv` kinds, decode only)."""
+    stacked KV pool `cache` holds (`_carries_kv` kinds), and `slots`
+    names the pool rows a prefill writes (attention_block)."""
     aux = jnp.float32(0.0)
     h = apply_norm(params["norm1"], x, cfg.norm)
     new_cache = cache
@@ -166,7 +157,7 @@ def _apply_layer(params, x, kind: str, cfg: ModelConfig, ctx: CiMContext,
                 causal=(kind != C.ENC_ATTN),
                 window=cfg.window if kind == C.LOCAL else None,
                 cache=cache, valid=valid, append=append, layer=layer,
-                **attn_kw)
+                slots=slots, **attn_kw)
         x = x + a
     elif kind == C.CROSS:
         a, new_cache = attention_block(params["attn"], h, causal=False,
@@ -420,14 +411,15 @@ class LM:
         return None
 
     def _run_stack(self, params, x, positions, caches, key, x_aux,
-                   valid=None, append=False, advance=False):
+                   valid=None, append=False, slots=None):
         """Prefix (unrolled) + body (scanned).  caches: None for training,
         else {"prefix": [...], "body": stacked-pytree}.
 
-        advance=True (decode): the body's `_carries_kv` pools ride the
-        scan carry and each layer writes only its new rows into them in
-        place; a donated pool then never leaves its buffer.  Other kinds,
-        and a cache being built (prefill), go through the scan's xs/ys."""
+        The body's `_carries_kv` pools ride the scan carry, and each
+        layer writes only its own rows into them in place (prefill,
+        decode and verify alike); a donated pool then never leaves its
+        buffer.  Other kinds' caches go through the scan's xs/ys.
+        `slots`: the pool rows a prefill writes (`LM.prefill_into`)."""
         cfg = self.cfg
         aux_total = jnp.float32(0.0)
         new_prefix = []
@@ -436,7 +428,8 @@ class LM:
                              None if key is None else jax.random.fold_in(key, i))
             c = None if caches is None else caches["prefix"][i]
             x, c2, aux = _apply_layer(params["prefix"][i], x, kind, cfg, ctx,
-                                      positions, c, x_aux, valid, append)
+                                      positions, c, x_aux, valid, append,
+                                      slots=slots)
             new_prefix.append(c2)
             aux_total += aux
         new_body = None
@@ -446,7 +439,7 @@ class LM:
                                                       jnp.uint32))
             body_caches = None if caches is None else caches["body"]
             pools = {}
-            if advance:
+            if caches is not None:
                 pools = {str(i): body_caches[str(i)]
                          for i, kind in enumerate(cfg.period)
                          if _carries_kv(kind, cfg)}
@@ -468,7 +461,8 @@ class LM:
                         ci = dict(pool, pos=pool["pos"][li])
                         h, c2, aux = _apply_layer(lp[str(i)], h, kind, cfg,
                                                   ctx, positions, ci, x_aux,
-                                                  valid, append, layer=li)
+                                                  valid, append, layer=li,
+                                                  slots=slots)
                         pools[str(i)] = dict(
                             c2, pos=pool["pos"].at[li].set(c2["pos"]))
                     else:
@@ -481,8 +475,9 @@ class LM:
                     aux_l += aux
                 return (h, pools), (cache_out, aux_l)
 
-            # decode never differentiates: no remat around the carried pool
-            step = jax.checkpoint(step) if cfg.remat and not advance else step
+            # only training differentiates: no remat around a carried pool
+            step = jax.checkpoint(step) if cfg.remat and caches is None \
+                else step
             xs = (params["body"], keys, jnp.arange(cfg.n_periods),
                   body_caches)
             # the scan body traces ONCE but executes n_periods times:
@@ -494,7 +489,7 @@ class LM:
                 (x, pools), (new_body, auxes) = jax.lax.scan(
                     step, (x, pools), xs)
             aux_total += auxes.sum()
-            if advance:
+            if caches is not None:
                 new_body = {str(i): pools.get(str(i), new_body.get(str(i)))
                             for i in range(len(cfg.period))}
         return x, {"prefix": new_prefix, "body": new_body}, aux_total
@@ -560,7 +555,22 @@ class LM:
         return {"prefix": prefix, "body": body}
 
     def prefill(self, params, batch, key=None):
-        """Fill pre-allocated caches; return (last-token logits, caches).
+        """Prefill into caches it allocates (``batch["max_len"]`` deep,
+        the prompt length by default); return (last-token logits,
+        caches).  `prefill_into` documents the batch."""
+        b, s = batch["tokens"].shape
+        caches = self.init_caches(b, batch.get("max_len", s),
+                                  per_slot=batch.get("lengths") is not None)
+        return self.prefill_into(params, caches, batch, key=key)
+
+    def prefill_into(self, params, caches, batch, slots=None, key=None):
+        """Write the prompts' K/V into `caches` from position 0 as whole
+        rows; return (last-token logits, caches).
+
+        slots: (B,) the rows of a slot pool the prompts fill (serving
+        admission); a slot past the pool (the admission padding) is
+        dropped and every other row is left as it was.  None fills rows
+        0..B-1 of caches B rows wide.
 
         Ragged batches: pass ``batch["lengths"]`` ((B,) true prompt
         lengths) and optionally ``batch["pad"]`` ("right", the default,
@@ -574,12 +584,9 @@ class LM:
         returns ``caches=None`` (a decode attempt fails loudly instead
         of silently attending to pad K/V).
         """
-        cfg = self.cfg
         tokens = batch["tokens"]
         b, s = tokens.shape
         lengths = batch.get("lengths")
-        caches = self.init_caches(b, batch.get("max_len", s),
-                                  per_slot=lengths is not None)
         ar = jnp.arange(s)[None, :]
         if lengths is None:
             positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -601,7 +608,7 @@ class LM:
         x = self._embed(params, tokens)
         x_aux = self._aux_stream(params, batch, key)
         x, caches, _ = self._run_stack(params, x, positions, caches, key,
-                                       x_aux, valid=valid)
+                                       x_aux, valid=valid, slots=slots)
         if lengths is None:
             logits = self._logits(params, x[:, -1:])
         else:
@@ -622,7 +629,7 @@ class LM:
                      else jnp.full((b, 1), pos, jnp.int32))
         x = self._embed_decode(params, tokens, positions)
         x, caches, _ = self._run_stack(params, x, positions, caches, key,
-                                       None, advance=True)
+                                       None)
         return self._logits(params, x), caches
 
     def decode_multi(self, params, caches, tokens, pos, key=None):
@@ -644,7 +651,7 @@ class LM:
                      else jnp.broadcast_to(pos + off, (b, kk)))
         x = self._embed_decode(params, tokens, positions)
         x, caches, _ = self._run_stack(params, x, positions, caches, key,
-                                       None, append=True, advance=True)
+                                       None, append=True)
         return self._logits(params, x), caches
 
     def _embed_decode(self, params, tokens, positions):

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence
 
 from jax.profiler import TraceAnnotation
 
@@ -122,15 +122,8 @@ class EngineTelemetry:
             "rolling logit NMED per sentinel lane")
         self.kv_inplace_g = r.gauge(
             "repro_serving_kv_inplace_layers",
-            "layers whose KV the lane's compiled decode writes in place")
-        self.decode_attn_kernel_g = r.gauge(
-            "repro_serving_decode_attn_kernel_layers",
-            "layers whose decode attention the decode_attn kernel serves")
-        self.decode_kv_rows_c = r.counter(
-            "repro_serving_decode_kv_rows_total",
-            "KV pool rows per decode round: kind=read, block-rounded live "
-            "rows the decode_attn kernel reads; kind=pool, slots x "
-            "max_len per carried layer")
+            "layers whose KV the lane's compiled prefill and decode write "
+            "in place")
         self.energy_g = r.gauge(
             "repro_serving_energy_joules",
             "estimated energy attributed per lane")
@@ -230,9 +223,6 @@ class EngineTelemetry:
         n = getattr(backend, "kv_inplace_layers", None)
         if n is not None:
             self.kv_inplace_g.set(n, lane=lane)
-        n = getattr(backend, "decode_attn_kernel_layers", None)
-        if n is not None:
-            self.decode_attn_kernel_g.set(n, lane=lane)
 
     def on_warmup(self, engine) -> None:
         """Build the per-lane energy meters (eval_shape MAC profiling;
@@ -269,16 +259,11 @@ class EngineTelemetry:
             self._update_energy(lane, m)
 
     def on_decode_round(self, lane: str, rids: Sequence[int],
-                        dur: float,
-                        kv_rows: Optional[Tuple[int, int]] = None) -> None:
-        """After a `decode_round` span of `dur` seconds closed;
-        `kv_rows` is the lane's (read, pool) KV rows for the round."""
+                        dur: float) -> None:
+        """After a `decode_round` span of `dur` seconds closed."""
         if not self.registry.enabled:
             return
         self.decode_rounds_c.inc(1, tier=lane)
-        if kv_rows is not None:
-            self.decode_kv_rows_c.inc(kv_rows[0], lane=lane, kind="read")
-            self.decode_kv_rows_c.inc(kv_rows[1], lane=lane, kind="pool")
         self.decode_h.observe(dur, tier=lane)
         m = self.meters.get(lane)
         if m is not None:

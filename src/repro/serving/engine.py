@@ -231,7 +231,7 @@ def servable_archs(smoke: bool = True) -> List[str]:
 
 class LMLaneBackend:
     """Slot-pool execution for one (LM, CiM tier): pre-jitted ragged
-    group prefill, cache scatter-insert, and full-pool decode.
+    group prefill straight into the pool's slots, and full-pool decode.
 
     With `mesh` (DESIGN.md §11) the pool is **data-parallel sharded**:
     slots (the cache batch dim) spread over the mesh's data axes,
@@ -250,9 +250,7 @@ class LMLaneBackend:
         import jax
         import jax.numpy as jnp
 
-        from repro.kernels.decode_attn import kv_block
-        from repro.models.transformer import (decode_attn_kernel_layers,
-                                              kv_inplace_layers)
+        from repro.models.transformer import kv_inplace_layers
 
         check_engine_arch(lm.cfg)
         self.lm, self.params = lm, params
@@ -291,49 +289,21 @@ class LMLaneBackend:
         self.last_prefill_logits: Optional[np.ndarray] = None
         self.last_decode_logits: Optional[np.ndarray] = None
         self.telemetry = None        # obs.EngineTelemetry, set by the engine
-        # the decode below writes these layers' KV into the pool in place
+        # prefill and decode write these layers' KV into the pool in place
         self.kv_inplace_layers = kv_inplace_layers(lm.cfg)
-        # ... and, lowered for a TPU with no mesh, attends over them with
-        # kernels.decode_attn, `_kv_rows` pool rows per block
-        self.decode_attn_kernel_layers = 0 if mesh is not None else \
-            decode_attn_kernel_layers(lm.cfg, lm.cim, jax.default_backend())
-        cfg = lm.cfg
-        self._kv_rows = kv_block(self.max_len, cfg.n_kv_heads * cfg.head_dim_,
-                                 cfg.head_dim_,
-                                 jnp.dtype(jnp.bfloat16).itemsize)[0]
 
-        # max_len must be a trace-time constant (it sizes the group
-        # caches), so it is closed over — same trick as launch/serve.py
-        def _prefill(p, toks, lens):
-            return lm.prefill(p, {"tokens": toks, "lengths": lens,
-                                  "max_len": self.max_len})
+        # admission prefills straight into the pool rows named by
+        # `slots`; the sentinel slot == n_slots (admission padding) is
+        # out of range and dropped, never clamped onto a live row
+        def _prefill(p, caches, toks, lens, slots):
+            return lm.prefill_into(p, caches, {"tokens": toks,
+                                               "lengths": lens}, slots)
 
-        self._prefill = jax.jit(_prefill)
-        # decode caches are donated: each round's pool buffers die the
-        # moment the next round's exist (in-place update on TPU;
-        # ignored with a warning on CPU)
+        # the pool is donated to both: each call's pool buffers die the
+        # moment the next call's exist (in-place update on TPU; ignored
+        # with a warning on CPU)
+        self._prefill = jax.jit(_prefill, donate_argnums=(1,))
         self._decode = jax.jit(lm.decode_step, donate_argnums=(1,))
-
-        def _insert(lane, grp, slots):
-            # scatter group-cache rows into the pool rows named by
-            # `slots`; the sentinel slot == n_slots (admission padding)
-            # is out of range and dropped, never clamped onto a live row
-            def pre(d, s):
-                return d.at[slots].set(s.astype(d.dtype), mode="drop")
-
-            def body(d, s):
-                return d.at[:, slots].set(s.astype(d.dtype), mode="drop")
-
-            out = {"prefix": [jax.tree_util.tree_map(pre, lp, gp)
-                              for lp, gp in zip(lane["prefix"],
-                                                grp["prefix"])],
-                   "body": None}
-            if lane["body"] is not None:
-                out["body"] = jax.tree_util.tree_map(body, lane["body"],
-                                                     grp["body"])
-            return out
-
-        self._insert = jax.jit(_insert, donate_argnums=(0,))
         self._jnp = jnp
 
     def _ctx(self):
@@ -407,27 +377,15 @@ class LMLaneBackend:
                 lens[i] = len(pr)
                 slot_idx[i] = sl
             with self._ctx():
-                logits, grp = self._prefill(self.params, jnp.asarray(toks),
-                                            jnp.asarray(lens))
-                self.caches = self._insert(self.caches, grp,
-                                           jnp.asarray(slot_idx))
+                logits, self.caches = self._prefill(
+                    self.params, self.caches, jnp.asarray(toks),
+                    jnp.asarray(lens), jnp.asarray(slot_idx))
         first, lg = self._sample(logits, "prefill.fetch", "prefill.sample")
         self.last_prefill_logits = lg[:g]
         for i, sl in enumerate(slots):
             self.slot_tokens[sl] = first[i]
             self.slot_pos[sl] = lens[i]
         return first[:g]
-
-    def decode_kv_rows(self) -> Tuple[int, int]:
-        """(read, pool): the pool rows the next decode round's attention
-        reads through `kernels.decode_attn` (each slot's live rows,
-        rounded up to whole blocks, in every kernel layer), and the rows
-        its carried layers hold (slots x max_len each)."""
-        from repro.kernels.decode_attn import live_rows
-
-        read = live_rows(self.slot_pos, self.max_len, self._kv_rows).sum()
-        return (self.decode_attn_kernel_layers * int(read),
-                self.kv_inplace_layers * self.n_slots * self.max_len)
 
     def decode_round(self) -> np.ndarray:
         """One greedy decode step for the whole pool (idle slots ride
@@ -452,8 +410,8 @@ class LMLaneBackend:
         return nxt
 
     def warmup(self) -> int:
-        """Compile every steady-state executable: (G, P) prefills +
-        inserts, and the pool decode.  The sentinel-slot inserts and the
+        """Compile every steady-state executable: (G, P) prefills and
+        the pool decode.  The sentinel-slot prefills and the
         zero-position decode leave no live state behind (idle rows are
         fully overwritten on first real admission).  Warm-up is not
         served work: it records no spans."""
@@ -471,9 +429,9 @@ class LMLaneBackend:
                 for g_bkt in self.group_buckets:
                     toks = jnp.zeros((g_bkt, p_bkt), jnp.int32)
                     lens = jnp.full((g_bkt,), p_bkt, jnp.int32)
-                    logits, grp = self._prefill(self.params, toks, lens)
                     sent = jnp.full((g_bkt,), self.n_slots, jnp.int32)
-                    self.caches = self._insert(self.caches, grp, sent)
+                    logits, self.caches = self._prefill(
+                        self.params, self.caches, toks, lens, sent)
                     self._fetch(logits)    # compiles the sampling slice
                     n += 1
         self.decode_round()                # pool decode (+ sampling slice)
@@ -806,17 +764,13 @@ class ServingEngine:
                 if tel is None:
                     nxt = lane.backend.decode_round()
                 else:
-                    kv_rows = (lane.backend.decode_kv_rows()
-                               if hasattr(lane.backend, "decode_kv_rows")
-                               else None)
                     with tel.span("decode_round", lane.name,
                                   rows=lane.backend.n_slots,
                                   useful=len(lane.running)) as sp:
                         nxt = lane.backend.decode_round()
                     tel.on_decode_round(
                         lane.name, [r.result.rid for r in
-                                    lane.running.values()], sp.dur,
-                        kv_rows)
+                                    lane.running.values()], sp.dur)
             except LaneHealthError as e:
                 if sen is None:
                     raise

@@ -40,8 +40,8 @@ on the verifier tier produces, whatever the drafter says; the drafter
 only controls *throughput* (acceptance rate), never *output*.
 
 **Cache invariant:** pool entries at positions >= fill are zero —
-established at init (zeros), prefill (pad K/V zeroed), insert (full-row
-scatter), decode (writes exactly at fill), and maintained by rollback.
+established at init (zeros), prefill (pad K/V zeroed, whole rows
+written), decode (writes exactly at fill), and maintained by rollback.
 It is what makes a rolled-back cache *byte-identical* to one that never
 drafted, which the KV-rollback tests compare directly.
 """
@@ -58,7 +58,7 @@ from .engine import LMLaneBackend
 class SpecDecodeBackend(LMLaneBackend):
     """Slot-pool lane that decodes speculatively: drafter tier guesses,
     verifier tier (per-token exact) scores all guesses in one batched
-    pass.  Prefill/insert run on the verifier (inherited), so admitted
+    pass.  Prefill runs on the verifier (inherited), so admitted
     context is exact from the first token.
 
     `draft_ks` is the set of pre-warmed draft depths; `set_draft_k`
@@ -275,7 +275,7 @@ class SpecDecodeBackend(LMLaneBackend):
 
     # -- warmup ------------------------------------------------------------
     def warmup(self) -> int:
-        """Inherited warmup (prefill/insert/plain decode/sampling), then
+        """Inherited warmup (prefill/plain decode/sampling), then
         one idle spec round per configured draft depth — so depth
         switches after warmup are dict lookups, never retraces.  The
         idle rounds leave no live state: remaining=0 everywhere means

@@ -14,7 +14,7 @@ interpret mode on the CPU:
     scalar (lockstep) fill level, at the last layer of an L = 3 pool;
   * no block past a slot's last live block reaches the computation;
   * `LM.decode_step` with the kernel gives the XLA path's greedy tokens;
-  * block shapes and the block-rounded live rows.
+  * block shapes.
 """
 
 import dataclasses
@@ -26,8 +26,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.decode_attn import kv_block, live_rows, \
-    pool_decode_attention
+from repro.kernels.decode_attn import kv_block, pool_decode_attention
 from repro.models import attention
 from repro.models.attention import attention_block, init_attention
 from repro.models.common import OFF
@@ -110,8 +109,9 @@ def test_kernel_reads_no_block_past_the_fill_level(layout):
     _, kp, vp, _, rows, t = _pool_case(layout)
     pos = _fills(rows, t, "slot")
     q = jax.random.normal(jax.random.PRNGKey(3), (6, 1, h, d))
-    dead = (jnp.arange(t)[None, :]
-            >= jnp.asarray(live_rows(np.asarray(pos), t, rows))[:, None])
+    live = np.minimum(np.asarray(pos) + 1, t)      # whole blocks read
+    dead = jnp.arange(t)[None, :] >= jnp.asarray(-(-live // rows) * rows
+                                                 )[:, None]
     dead = dead[None, :, :, None]
     run = functools.partial(pool_decode_attention, q, layer=L - 1,
                             pos=pos, interpret=True)
@@ -174,9 +174,3 @@ def test_kv_block_shapes():
     assert kv_block(768, 2048, 64, 2) == (256, 2048)
     assert kv_block(100, 2048, 64, 2) == (100, 2048)
     assert kv_block(40, 64, 16, 2) == (40, 64)
-
-
-def test_live_rows_round_up_to_blocks():
-    np.testing.assert_array_equal(
-        live_rows([0, 255, 256, 600, 4095, 5000], 4096, 256),
-        [256, 256, 512, 768, 4096, 4096])

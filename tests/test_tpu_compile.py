@@ -5,10 +5,10 @@ v5e chip (`jax.experimental.topologies`): nothing runs, but the TPU
 compiler accepts or refuses the kernel exactly as it would on the chip,
 and the compiled text holds the Mosaic kernel (`tpu_custom_call`).
 Every block the autotuner can hand the kernel at those shapes is
-compiled.  The lane's donated decode is compiled the same way at
-published widths, from abstract shapes, to hold its KV pool in place
-and to attend with the `decode_attn` kernel where the pool lies.
-The topology is described in a module fixture, never at import, so
+compiled.  The lane's donated decode and admission prefill are
+compiled the same way at published widths, from abstract shapes, to
+hold the KV pool in place, and the decode to attend with the
+`decode_attn` kernel where the pool lies.  The topology is described in a module fixture, never at import, so
 test collection stays identical on every worker.
 """
 
@@ -119,13 +119,14 @@ def test_every_tpu_block_obeys_the_tiling_rule():
 
 
 # served decode pools: stablelm-2-1.6b's 6 slots and qwen3-1.7b's 8, each
-# 4096 deep, on the exact tier
+# 4096 deep, on the exact tier; and each lane's longest prompt bucket
 DECODE_POOLS = {"stablelm-1.6b": (6, 4096), "qwen3-1.7b": (8, 4096)}
+PROMPT_BUCKETS = {"stablelm-1.6b": 3584, "qwen3-1.7b": 512}
 
 
-def _lane_decode(one_chip, arch):
-    """The exact lane's donated decode at `DECODE_POOLS[arch]`, compiled
-    for the described chip from abstract shapes."""
+def _lane_shapes(one_chip, arch):
+    """The exact lane's LM, and its params and `DECODE_POOLS[arch]` pool
+    as abstract shapes on the described chip."""
     slots, depth = DECODE_POOLS[arch]
     tier = build_tiers(mode="surrogate_fast", families=("exact",))[0]
     lm = LM(dataclasses.replace(get_config(arch), cim=tier.cim))
@@ -137,6 +138,14 @@ def _lane_decode(one_chip, arch):
         on_chip, jax.eval_shape(lm.init, jax.random.PRNGKey(0)))
     caches = jax.tree_util.tree_map(on_chip, jax.eval_shape(
         lambda: lm.init_caches(slots, depth, per_slot=True)))
+    return lm, params, caches
+
+
+def _lane_decode(one_chip, arch):
+    """The exact lane's donated decode at `DECODE_POOLS[arch]`, compiled
+    for the described chip from abstract shapes."""
+    slots = DECODE_POOLS[arch][0]
+    lm, params, caches = _lane_shapes(one_chip, arch)
     tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(lm.decode_step, donate_argnums=(1,)).lower(
@@ -144,15 +153,10 @@ def _lane_decode(one_chip, arch):
     return compiled, lm.cfg
 
 
-@pytest.mark.parametrize("arch", DECODE_POOLS)
-def test_lane_decode_writes_kv_in_place(one_chip, arch):
-    """The lane's donated decode writes its new K/V rows into the
-    stacked pool in place: its scratch is under one layer's K+V, and no
-    copy or fresh buffer of the pool's shape is in the compiled text (a
-    pool fed through the layer scan's xs/ys took 5.45 GB of scratch at
-    stablelm's widths, two fresh pools and two whole-pool copies)."""
+def _assert_pool_in_place(compiled, cfg, arch):
+    """Scratch under one layer's K+V, and no copy or fresh buffer of the
+    pool's shape in the compiled text."""
     slots, depth = DECODE_POOLS[arch]
-    compiled, cfg = _lane_decode(one_chip, arch)
     khd = cfg.n_kv_heads * cfg.head_dim_
     layer_kv = 2 * slots * depth * khd * 2          # bf16 K and V
     assert compiled.memory_analysis().temp_size_in_bytes < layer_kv
@@ -162,6 +166,38 @@ def test_lane_decode_writes_kv_in_place(one_chip, arch):
                                    (" copy(", " copy-start(",
                                     "AllocateBuffer"))]
     assert not moved, moved[:2]
+
+
+@pytest.mark.parametrize("arch", DECODE_POOLS)
+def test_lane_decode_writes_kv_in_place(one_chip, arch):
+    """The lane's donated decode writes its new K/V rows into the
+    stacked pool in place (a pool fed through the layer scan's xs/ys
+    took 5.45 GB of scratch at stablelm's widths, two fresh pools and
+    two whole-pool copies)."""
+    compiled, cfg = _lane_decode(one_chip, arch)
+    _assert_pool_in_place(compiled, cfg, arch)
+
+
+@pytest.mark.parametrize("arch", DECODE_POOLS)
+def test_lane_prefill_writes_kv_in_place(one_chip, arch):
+    """The lane's donated admission prefill (one prompt, the longest
+    prompt bucket) writes the prompt's K/V rows into its slot of the
+    stacked pool in place (a prefill that built its own group cache
+    returned a (L, 1, T, KH*D) buffer, 0.81 GB at stablelm's widths,
+    for a second executable to scatter into the pool)."""
+    lm, params, caches = _lane_shapes(one_chip, arch)
+
+    def prefill(p, c, toks, lens, slots):
+        return lm.prefill_into(p, c, {"tokens": toks, "lengths": lens},
+                               slots)
+
+    def one(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, caches, one((1, PROMPT_BUCKETS[arch])), one((1,)),
+        one((1,))).compile()
+    _assert_pool_in_place(compiled, lm.cfg, arch)
 
 
 @pytest.mark.parametrize("arch", DECODE_POOLS)
